@@ -7,7 +7,6 @@
 from __future__ import annotations
 
 import argparse
-import sys
 import time
 
 
@@ -38,10 +37,7 @@ def main() -> None:
 
     from benchmarks import roofline
 
-    try:
-        roofline.run()
-    except Exception as e:  # artifacts may not exist yet
-        print(f"[roofline] skipped: {e}", file=sys.stderr)
+    roofline.run()
 
     if not args.skip_convergence:
         from benchmarks import table1_convex, table2_nonconvex, table4_comm_cost

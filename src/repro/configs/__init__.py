@@ -3,6 +3,8 @@
 Each module exposes ``FULL`` (the exact assigned config) and ``SMOKE``
 (reduced variant: ≤2-3 layers, d_model ≤ 512, ≤4 experts) of the same family.
 """
+from typing import Optional
+
 from repro.configs.base import (
     ArchConfig,
     AttentionConfig,
@@ -53,11 +55,21 @@ SWA_VARIANT_FOR_LONG = {
 LONG_WINDOW = 8192
 
 
-def get_arch(name: str, smoke: bool = False) -> ArchConfig:
+def get_arch(name: str, smoke: bool = False,
+             layers: Optional[int] = None) -> ArchConfig:
+    """The registered config; ``layers`` cuts its depth to that many
+    layers and keeps every width (the cut a chip's share of a model
+    takes)."""
     if name not in ARCHS:
         raise KeyError(f"unknown arch {name!r}; choose from {sorted(ARCHS)}")
     mod = ARCHS[name]
-    return mod.SMOKE if smoke else mod.FULL
+    cfg = mod.SMOKE if smoke else mod.FULL
+    if layers is None:
+        return cfg
+    if not 1 <= layers <= cfg.n_layers:
+        raise ValueError(f"layers={layers} outside 1..{cfg.n_layers} "
+                         f"for {cfg.name}")
+    return cfg.replace(n_layers=layers)
 
 
 def arch_for_shape(name: str, shape: str, smoke: bool = False) -> ArchConfig:

@@ -87,9 +87,25 @@ def batch_spec(cfg: ArchConfig, client_axis: Optional[str], extra_data_axis: boo
     return spec
 
 
+def _client_sharded(tree, mesh, client_axis):
+    """Constrain the leading (client) dim of every leaf to ``client_axis``
+    on ``mesh``, other dims left to XLA. Without it the broadcast
+    consensus comes out replicated: every device would hold every
+    client's replica."""
+    if mesh is None:
+        return tree
+
+    def one(x):
+        spec = P(client_axis, *([P.UNCONSTRAINED] * (x.ndim - 1)))
+        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+    return jax.tree.map(one, tree)
+
+
 def build_sync_step(reducer=None, *, base_seed: int = 0,
                     streaming: bool = False, hierarchical: bool = False,
-                    n_pods: int = 2, inter_reducer="int8"):
+                    n_pods: int = 2, inter_reducer="int8", mesh=None,
+                    client_axis="data"):
     """Reducer-aware Algorithm 1 line 5: the parameter-averaging round.
 
     Returns ``sync_step(state) -> state``. With the default DenseMean this is
@@ -132,6 +148,10 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
     keep the flat contract exactly: ``n_pods=1`` (no inter-pod link
     exists) and dense∘dense (the two-level mean collapses to the flat
     mean) both produce the flat round bit-exactly.
+
+    ``mesh`` (with the ``client_axis`` the replicas are sharded over)
+    keeps the round's output params and moments split over the client
+    axis, as its input was.
     """
     reducer = get_reducer(reducer)
     dense = isinstance(reducer, DenseMean)
@@ -141,7 +161,8 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
             raise ValueError(f"n_pods must be >= 1, got {n_pods}")
         if n_pods > 1:
             return _build_two_level_sync_step(reducer, n_pods, inter_reducer,
-                                              base_seed, streaming)
+                                              base_seed, streaming, mesh,
+                                              client_axis)
         # n_pods == 1: a single pod has no inter-pod hop to cross — the
         # round degenerates to the flat round with the intra reducer
         # (streaming or blocking; bit-exact with the flat path by
@@ -175,6 +196,8 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
                 consensus, comm = reducer.reduce(state["params"], comm, rng)
                 params = tree_broadcast_leading(consensus, n)
             out = dict(state, params=params, opt=opt, comm=comm)
+        out.update(_client_sharded({"params": out["params"],
+                                    "opt": out["opt"]}, mesh, client_axis))
         return out
 
     # tag the step with its reducer (and round structure) so
@@ -187,7 +210,8 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
 
 
 def _build_two_level_sync_step(intra, n_pods: int, inter_reducer,
-                               base_seed: int, streaming: bool = False):
+                               base_seed: int, streaming: bool = False,
+                               mesh=None, client_axis="data"):
     """The hierarchical (n_pods > 1) round behind ``build_sync_step``.
 
     One ``engine.Hierarchical.reduce`` per sync — the same code path the
@@ -230,6 +254,8 @@ def _build_two_level_sync_step(intra, n_pods: int, inter_reducer,
             consensus, comm = topo.reduce(state["params"], comm, rng)
             out = dict(state, params=tree_broadcast_leading(consensus, n),
                        opt=opt, comm=comm)
+        out.update(_client_sharded({"params": out["params"],
+                                    "opt": out["opt"]}, mesh, client_axis))
         return out
 
     # tags: the driver prices the topology the round actually executes
@@ -280,8 +306,7 @@ def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
                       sync_grads: bool = False,
                       reducer=None,
                       streaming: bool = False,
-                      inter_reducer=None,
-                      donate: bool = True):
+                      inter_reducer=None):
     """Returns (train_step_local, sync_step, specs) for the given mesh.
 
     train_step_local(state, batch, eta) -> (state, metrics)
@@ -374,9 +399,11 @@ def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
 
     sync_step = (build_sync_step(reducer, streaming=streaming,
                                  hierarchical=True, n_pods=n_pods,
-                                 inter_reducer=inter_reducer)
+                                 inter_reducer=inter_reducer, mesh=mesh,
+                                 client_axis=client_axis)
                  if two_level else
-                 build_sync_step(reducer, streaming=streaming))
+                 build_sync_step(reducer, streaming=streaming, mesh=mesh,
+                                 client_axis=client_axis))
 
     return train_step_local, sync_step, per_client_step
 
@@ -402,8 +429,21 @@ def state_shardings(cfg: ArchConfig, mesh, params_shape, opt_shape,
             "step": NamedSharding(mesh, P())}
 
 
+def init_sharded_state(rng, cfg: ArchConfig, n_clients: int, mesh,
+                       optimizer: str = "sgd", client_axis="data"):
+    """``init_state`` compiled straight into ``state_shardings`` on
+    ``mesh``: each device materialises only the replicas it holds, so no
+    replica set is first built whole on one device."""
+    shape = init_state_shape(cfg, n_clients, optimizer)
+    sh = state_shardings(cfg, mesh, shape["params"], shape["opt"],
+                         client_axis=client_axis)
+    return jax.jit(lambda k: init_state(k, cfg, n_clients, optimizer),
+                   out_shardings=sh)(rng)
+
+
 def init_state(rng, cfg: ArchConfig, n_clients: int, optimizer: str = "sgd"):
-    """Materialised training state with client replicas (small configs only)."""
+    """Materialised training state with client replicas, on the default
+    device (``init_sharded_state`` places it on a mesh)."""
     opt_init, _ = make_optimizer(optimizer)
     params = TF.init_params(rng, cfg)
     opt = opt_init(params)

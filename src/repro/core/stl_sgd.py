@@ -15,6 +15,7 @@ steps.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional
 
@@ -86,6 +87,12 @@ class StageResult:
     iters: int
     rounds: int
     mean_loss: float
+    losses: List[float] = field(default_factory=list)   # per local step
+    # host seconds per local step / per sync round, each ended by
+    # jax.block_until_ready on the new state (the first of each includes
+    # its compile)
+    step_s: List[float] = field(default_factory=list)
+    sync_s: List[float] = field(default_factory=list)
 
 
 @dataclass
@@ -124,7 +131,7 @@ class DriverBackend:
         drv, ds = self.driver, self.ds
         if drv.uses_center:
             ds.center = tree_mean_leading(ds.state["params"])
-        losses = []
+        losses, step_s, sync_s = [], [], []
         status = StageStatus()
         done = 0
         tracer = engine.tracer
@@ -135,12 +142,15 @@ class DriverBackend:
                                     "eta": stage.eta}):
                 for _ in range(burst):
                     batch = next(self.it)
+                    t0 = time.perf_counter()
                     if drv.uses_center:
                         ds.state, m = drv.train_step(ds.state, batch,
                                                      stage.eta, ds.center)
                     else:
                         ds.state, m = drv.train_step(ds.state, batch,
                                                      stage.eta)
+                    jax.block_until_ready(ds.state)
+                    step_s.append(time.perf_counter() - t0)
                     losses.append(float(m["loss"]))
                     done += 1
                     ds.iters_total += 1
@@ -148,7 +158,9 @@ class DriverBackend:
                         break
             with tracer.span("reduce", cat=CAT_COMM, track="driver",
                              attrs=dict(drv.span_attrs, s=stage.s)):
-                ds.state = drv.sync_step(ds.state)
+                t0 = time.perf_counter()
+                ds.state = jax.block_until_ready(drv.sync_step(ds.state))
+                sync_s.append(time.perf_counter() - t0)
             status.rounds += 1
             ds.rounds_total += 1
             if self.max_iters and ds.iters_total >= self.max_iters:
@@ -157,7 +169,7 @@ class DriverBackend:
         status.iters = done
         res = StageResult(stage.s, stage.eta, stage.k, done, status.rounds,
                           float(jnp.mean(jnp.asarray(losses))) if losses
-                          else float("nan"))
+                          else float("nan"), losses, step_s, sync_s)
         ds.results.append(res)
         engine.metrics.gauge(
             "train.stage_objective", unit="loss",

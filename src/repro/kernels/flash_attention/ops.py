@@ -1,6 +1,6 @@
 """Jit'd public wrapper for the flash-attention kernel.
 
-``flash_attention(..., impl=)``:
+``flash_attention(..., impl=)``, ``impl`` required:
   * "pallas"     — TPU kernel (deploy target)
   * "interpret"  — same kernel body executed in Python on CPU (validation)
   * "xla"        — the pure-jnp oracle (ref.py)
@@ -50,7 +50,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None,
-                    impl: str = "interpret"):
+                    impl: str):
     if impl == "xla":
         return R.attention_ref(q, k, v, causal=causal, window=window,
                                softcap=softcap, scale=scale).astype(q.dtype)

@@ -1,4 +1,8 @@
-"""Jit'd wrapper for the fused update kernel + pytree-level API."""
+"""Jit'd wrapper for the fused update kernel + pytree-level API.
+
+``impl`` is required: "pallas" (TPU) | "interpret" (CPU validation) |
+"xla" (oracle).
+"""
 from __future__ import annotations
 
 import jax
@@ -8,7 +12,7 @@ from repro.kernels.fused_update.kernel import fused_sgd_update
 
 
 def sgd_update(p, m, g, *, eta: float, beta: float = 0.0, wd: float = 0.0,
-               impl: str = "interpret"):
+               impl: str):
     """Single-leaf fused momentum-SGD update."""
     if impl == "xla":
         return R.sgd_update_ref(p, m, g, eta=eta, beta=beta, wd=wd)
@@ -17,7 +21,7 @@ def sgd_update(p, m, g, *, eta: float, beta: float = 0.0, wd: float = 0.0,
 
 
 def tree_sgd_update(params, moments, grads, *, eta, beta=0.0, wd=0.0,
-                    impl: str = "interpret"):
+                    impl: str):
     """Fused update over a whole parameter pytree."""
     flat_p, treedef = jax.tree.flatten(params)
     flat_m = treedef.flatten_up_to(moments)

@@ -13,10 +13,11 @@ The compressed communication round has two memory-bound halves:
     intermediate ever hits HBM.
 
 Tiling mirrors ``fused_update``: flat 1-D view, 128-lane blocks. Random bits
-are *passed in* (jax.random outside) rather than drawn from the on-core PRNG
-so the kernel is deterministic, CPU-interpretable, and bit-exact against
-``ref.py``. int8 TPU tiles want (32, 128) alignment; the flat view is padded
-to the block size so compiled mode sees aligned tiles.
+are *passed in* (jax.random outside, viewed as int32) rather than drawn from
+the on-core PRNG so the kernel is deterministic, CPU-interpretable, and
+bit-exact against ``ref.py``, whose ``uniform_from_bits`` both use. int8
+TPU tiles want (32, 128) alignment; the flat view is padded to the block
+size so compiled mode sees aligned tiles.
 """
 from __future__ import annotations
 
@@ -26,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_INV_2_32 = 1.0 / 4294967296.0
+from repro.kernels.quantize.ref import uniform_from_bits
 
 # int8 arrays tile as (32, 128) on TPU (4× the f32 (8, 128) sublane
 # packing). Interpret mode happily runs any block shape, which would let
@@ -57,8 +58,7 @@ def _quant_kernel(x_ref, r_ref, s_ref, q_ref, *, qmax):
     x = x_ref[...].astype(jnp.float32)
     s = s_ref[0, 0]
     y = x / s * qmax
-    u = r_ref[...].astype(jnp.float32) * _INV_2_32
-    q = jnp.floor(y + u)
+    q = jnp.floor(y + uniform_from_bits(r_ref[...]))
     q_ref[...] = jnp.clip(q, -qmax, qmax).astype(jnp.int8)
 
 
@@ -84,7 +84,8 @@ def quantize_kernel(x, rand_bits, scale, *, bits: int = 8,
         out_specs=pl.BlockSpec((brows, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.int8),
         interpret=interpret,
-    )(flat(x.astype(jnp.float32)), flat(rand_bits),
+    )(flat(x.astype(jnp.float32)),
+      flat(jax.lax.bitcast_convert_type(rand_bits, jnp.int32)),
       jnp.asarray(scale, jnp.float32).reshape(1, 1))
     return q.reshape(-1)[:n].reshape(shape)
 

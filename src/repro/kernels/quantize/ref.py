@@ -21,9 +21,24 @@ ops-vs-ref parity is bit-exact given the same random bits.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 _INV_2_32 = 1.0 / 4294967296.0  # uint32 bits -> U[0,1)
+
+
+def uniform_from_bits(bits):
+    """Random bits -> U[0,1) f32: ``float32(bits) * 2^-32``.
+
+    ``bits`` is uint32, or the same bits viewed as int32 (what the Pallas
+    kernel receives: the TPU kernel compiler has no uint32 -> f32 cast).
+    The 16-bit halves are exact in f32 and IEEE addition rounds their
+    exact sum once, so the result equals the direct cast bit for bit.
+    """
+    b = jax.lax.bitcast_convert_type(bits, jnp.int32)
+    hi = jax.lax.shift_right_logical(b, 16).astype(jnp.float32)
+    lo = (b & 0xFFFF).astype(jnp.float32)
+    return (hi * 65536.0 + lo) * _INV_2_32
 
 
 def qmax_for(bits: int) -> float:
@@ -37,8 +52,7 @@ def quantize_ref(x, rand_bits, scale, *, bits: int = 8):
     """
     qmax = qmax_for(bits)
     y = x.astype(jnp.float32) / scale * qmax
-    u = rand_bits.astype(jnp.float32) * _INV_2_32
-    q = jnp.floor(y + u)
+    q = jnp.floor(y + uniform_from_bits(rand_bits))
     return jnp.clip(q, -qmax, qmax).astype(jnp.int8)
 
 

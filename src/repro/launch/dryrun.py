@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, SHAPES
 from repro.launch import hlo_analysis as H
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs
 from repro.core import local_sgd as LS
 from repro.core import serving as SV
@@ -71,7 +71,7 @@ def dryrun_cell(arch: str, shape_name: str, mesh, *, verbose=True,
         input_specs(arch, shape_name, mesh, overrides=overrides))
     records = []
     want = lambda p: programs is None or p in programs
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         if kind == "train":
             state, batch, st_sh, b_sh, client_axis = rest
             if hierarchical and "pod" in mesh.axis_names:

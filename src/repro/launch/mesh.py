@@ -22,39 +22,23 @@ jax import, everything else sees the real 1-CPU topology.
 """
 from __future__ import annotations
 
-import contextlib
+import math
 
 import jax
 
-
-def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the AxisType
-    enum itself) only exist on newer releases; older ones are Auto-only."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def mesh_context(mesh):
-    """``jax.sharding.set_mesh(mesh)`` where available, else the classic
-    ``with mesh:`` context (pre-0.5 jax has no set_mesh)."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return contextlib.nullcontext(mesh) if mesh is None else mesh
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / CPU runs)."""
-    return _make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(_AUTO,) * 2)
 
 
 def make_host_pod_mesh(pods: int = 2, data: int = 1, model: int = 1):
@@ -65,7 +49,28 @@ def make_host_pod_mesh(pods: int = 2, data: int = 1, model: int = 1):
     under ``--xla_force_host_platform_device_count`` exactly as they would
     on pods (requires ``pods * data * model`` host devices).
     """
-    return _make_mesh((pods, data, model), ("pod", "data", "model"))
+    return jax.make_mesh((pods, data, model), ("pod", "data", "model"),
+                         axis_types=(_AUTO,) * 3)
+
+
+def make_client_mesh(n_clients: int, pods: int = 1):
+    """The training mesh over every device present: client replicas spread
+    over ``data`` (flat rounds, ``pods=1``) or over the ``(pod, data)``
+    grid pod-major (two-level rounds), ``model`` of size 1.
+
+    The pod axis takes ``gcd(pods, devices)`` chips, so one chip still
+    runs a two-level round (its pods then share the chip). Each device
+    holds ``n_clients / devices`` whole replicas; a client count the
+    devices do not divide is refused rather than replicated.
+    """
+    n = jax.device_count()
+    if n_clients % n:
+        raise ValueError(f"{n_clients} clients cannot be split evenly over "
+                         f"{n} devices")
+    if pods == 1:
+        return make_host_mesh(n, 1)
+    p = math.gcd(pods, n)
+    return make_host_pod_mesh(p, n // p, 1)
 
 
 # v5e hardware constants for the roofline (per chip / per link). The α–β

@@ -23,6 +23,7 @@ import argparse
 import jax
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as TF
 from repro.obs import write_chrome_trace, write_jsonl
 from repro.obs.metrics import MetricsRegistry
@@ -47,6 +48,10 @@ def main(argv=None):
                           "(arch is read from checkpoint meta)")
     src.add_argument("--arch", help="serve fresh random params for this arch")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="with --arch: cut the config to its first N "
+                         "layers, widths unchanged (a checkpoint's cut "
+                         "comes from its meta)")
     # traffic
     ap.add_argument("--process", default="poisson",
                     choices=["poisson", "bursty"])
@@ -79,6 +84,7 @@ def main(argv=None):
                          "(implies --profile)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     sched = SchedulerConfig(n_slots=args.slots, max_seq_len=args.max_seq_len,
                             max_queue=args.max_queue,
@@ -87,7 +93,7 @@ def main(argv=None):
         engine = ServeEngine.from_checkpoint(args.ckpt, scheduler=sched)
         cfg = engine.cfg
     else:
-        cfg = get_arch(args.arch, smoke=args.smoke)
+        cfg = get_arch(args.arch, smoke=args.smoke, layers=args.layers)
         params = TF.init_params(jax.random.key(args.seed), cfg)
         engine = ServeEngine(cfg, params, scheduler=sched)
 

@@ -1,13 +1,19 @@
 """Training launcher.
 
-Runs STL-SGD (or a baseline) on an (arch × mesh) with synthetic LM data.
-On this CPU container it drives reduced (smoke) configs end-to-end; on real
-TPU pods the same code paths run the full configs (the dry-run proves they
-lower/compile).
+Runs STL-SGD (or a baseline) on synthetic LM data over every device
+present: client replicas are spread over a ``data`` mesh axis (a
+``(pod, data)`` grid for ``--topology hier``), the state is initialised
+straight into its shardings and donated to the jitted local and sync
+steps. ``--smoke`` picks the reduced config for CPU runs; ``--layers N``
+cuts a published config to N layers at full width (recorded in the
+checkpoint meta).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-14b --smoke \
       --algo stl_sc --eta1 0.05 --k1 4 --T1 32 --stages 3 --steps 200
+  # MiniCPM3-4B at published widths, 4 layers, on one v5e chip
+  PYTHONPATH=src python -m repro.launch.train --arch minicpm3-4b \
+      --layers 4 --clients 2 --batch 1 --seq 2048 --momentum 0.9
 """
 from __future__ import annotations
 
@@ -17,6 +23,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.checkpoint import save_checkpoint
 from repro.configs import SHAPES, get_arch
@@ -25,7 +32,8 @@ from repro.core import local_sgd as LS
 from repro.core.stl_sgd import StagewiseDriver
 from repro.data.synthetic import make_token_stream
 from repro.engine import algorithm_names
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_client_mesh
 from repro.utils.logging import get_logger
 
 log = get_logger("train")
@@ -33,7 +41,7 @@ log = get_logger("train")
 
 def synthetic_batches(cfg, n_clients, batch_per_client, seq_len, seed=0,
                       non_iid=False):
-    """Infinite (C, B, S) token/label batches from per-client shards."""
+    """Infinite (C, B, S) host token/label batches from per-client shards."""
     shards = make_token_stream(200_000, cfg.vocab_size, n_clients, seed=seed,
                                non_iid=non_iid)
     rng = np.random.RandomState(seed)
@@ -47,11 +55,11 @@ def synthetic_batches(cfg, n_clients, batch_per_client, seq_len, seed=0,
         labs = np.stack([
             np.stack([shards[c, s + 1: s + seq_len + 1] for s in starts[c]])
             for c in range(n_clients)])
-        batch = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labs)}
+        batch = {"tokens": toks, "labels": labs}
         if cfg.frontend:
-            batch["frontend"] = jnp.asarray(fe_rng.randn(
+            batch["frontend"] = fe_rng.randn(
                 n_clients, batch_per_client, cfg.n_frontend_tokens,
-                cfg.frontend_dim).astype(np.float32), dtype=jnp.bfloat16)
+                cfg.frontend_dim).astype(jnp.bfloat16)
         yield batch
 
 
@@ -59,6 +67,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="cut the config to its first N layers, widths "
+                         "unchanged")
     ap.add_argument("--algo", default="stl_sc",
                     choices=list(algorithm_names()))
     ap.add_argument("--clients", type=int, default=4)
@@ -106,52 +117,59 @@ def main(argv=None):
                          "(implies --profile)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_arch(args.arch, smoke=args.smoke)
+    cfg = get_arch(args.arch, smoke=args.smoke, layers=args.layers)
     tcfg = TrainConfig(algo=args.algo, eta1=args.eta1, k1=args.k1, T1=args.T1,
                        n_stages=args.stages, iid=not args.non_iid,
                        gamma_inv=args.gamma_inv, momentum=args.momentum,
                        seed=args.seed, reducer=args.reducer,
                        topology=args.topology, n_pods=args.pods,
                        inter_reducer=args.inter_reducer)
-    mesh = make_host_mesh(1, 1)
     C = args.clients
+    hier = args.topology == "hier"
+    mesh = make_client_mesh(C, args.pods if hier else 1)
+    client_axis = ("pod", "data") if hier else "data"
 
-    log.info("arch=%s algo=%s clients=%d", cfg.name, args.algo, C)
-    state = LS.init_state(jax.random.key(args.seed), cfg, C, args.optimizer)
+    log.info("arch=%s layers=%d algo=%s clients=%d mesh=%s", cfg.name,
+             cfg.n_layers, args.algo, C, dict(mesh.shape))
+    state = LS.init_sharded_state(jax.random.key(args.seed), cfg, C, mesh,
+                                  args.optimizer, client_axis)
+    batch_sh = {k: NamedSharding(mesh, spec) for k, spec in
+                LS.batch_spec(cfg, client_axis, False).items()}
     train_local, sync_step, _ = LS.build_train_steps(
-        cfg, mesh, client_axis="data", optimizer=args.optimizer,
+        cfg, mesh, client_axis=client_axis, optimizer=args.optimizer,
         momentum=args.momentum, reducer=args.reducer,
         streaming=args.topology == "streaming")
-    if args.topology == "hier":
+    if hier:
         # the two-level round: dense intra-pod (args.reducer) + compressed
         # inter-pod — the driver prices it through engine.Hierarchical
         sync_step = LS.build_sync_step(args.reducer, hierarchical=True,
                                        n_pods=args.pods,
-                                       inter_reducer=args.inter_reducer)
+                                       inter_reducer=args.inter_reducer,
+                                       mesh=mesh, client_axis=client_axis)
 
     uses_center = args.algo in ("stl_nc1", "stl_nc2") and args.gamma_inv > 0
     if uses_center:
         from repro.core.prox import prox_loss
 
-        base = lambda p, c, b: LS.lm_loss(p, c, b)
         pl = prox_loss(lambda p, b: LS.lm_loss(p, cfg, b), args.gamma_inv)
-
-        def loss_with_center(p, c, b, center):
-            return pl(p, b, center)
 
         def train_with_center(state, batch, eta, center):
             # rebuild a step closing over the center
             tl, _, _ = LS.build_train_steps(
-                cfg, mesh, client_axis="data", optimizer=args.optimizer,
+                cfg, mesh, client_axis=client_axis,
+                optimizer=args.optimizer,
                 momentum=args.momentum,
                 loss_fn=lambda p, c, b: pl(p, b, center))
             return tl(state, batch, eta)
 
-        train_fn = jax.jit(lambda s, b, e, c: train_with_center(s, b, e, c))
+        train_fn = jax.jit(train_with_center, donate_argnums=(0,))
     else:
-        train_fn = jax.jit(train_local)
-    sync_fn = jax.jit(sync_step)
+        train_fn = jax.jit(train_local, donate_argnums=(0,))
+    # the state is donated to every step: the driver only ever holds the
+    # newest one, so one copy of it lives on the devices
+    sync_fn = jax.jit(sync_step, donate_argnums=(0,))
 
     profile = None
     if args.profile or args.profile_dir:
@@ -179,8 +197,8 @@ def main(argv=None):
             state["params"])
         sync_price["v"] = sum(
             h.time_s for h in driver.build_topology().hop_costs(template, C))
-    batches = synthetic_batches(cfg, C, args.batch, args.seq, args.seed,
-                                args.non_iid)
+    batches = (jax.device_put(b, batch_sh) for b in synthetic_batches(
+        cfg, C, args.batch, args.seq, args.seed, args.non_iid))
     tracer = None
     if args.trace:
         from repro.obs import Tracer
@@ -199,6 +217,9 @@ def main(argv=None):
     for r in ds.results:
         log.info("  stage %d: k=%d rounds=%d loss=%.4f", r.stage, r.k,
                  r.rounds, r.mean_loss)
+        log.info("    losses=%s", [round(x, 4) for x in r.losses])
+        log.info("    step_s=%s sync_s=%s", [round(x, 4) for x in r.step_s],
+                 [round(x, 4) for x in r.sync_s])
     if profile is not None:
         from repro.obs import format_skew_table
         profile.emit_spans(tracer)
@@ -223,6 +244,7 @@ def main(argv=None):
                                  ds.state["params"])
         meta = {
             "arch": args.arch, "smoke": bool(args.smoke),
+            "layers": cfg.n_layers,
             "algo": args.algo, "eta1": args.eta1, "k1": args.k1,
             "T1": args.T1, "n_stages": args.stages,
             "iters": ds.iters_total, "rounds": ds.rounds_total,

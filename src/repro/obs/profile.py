@@ -6,8 +6,8 @@ jitted steps on the host and reports the skew:
 
   * ``ProfileSession`` — a context manager that (optionally) wraps the
     run in a ``jax.profiler`` trace session (``logdir=`` writes the
-    XPlane/TensorBoard artifact; unavailable profilers degrade to wall
-    timing with a warning, never a crash) and records per-call
+    XPlane/TensorBoard artifact; a profiler that cannot start or stop
+    raises) and records per-call
     block-until-ready wall timings next to their modeled prices;
   * ``skew_table()`` — per-step-name rows ``{name, calls, modeled_s,
     measured_s, skew}`` where ``skew = measured / modeled`` (>1: the
@@ -31,26 +31,11 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Union
 
-from repro.obs.trace import CAT_COMPUTE, WALL
-from repro.utils.logging import get_logger
+import jax
 
-log = get_logger("obs.profile")
+from repro.obs.trace import CAT_COMPUTE, WALL
 
 __all__ = ["ProfileSession", "StepTiming", "format_skew_table"]
-
-
-def _block_until_ready(x):
-    """Wait for every jax array in ``x`` (pass-through for host values)."""
-    import jax
-
-    try:
-        return jax.block_until_ready(x)
-    except Exception:
-        # very old jax: per-leaf fallback
-        for leaf in jax.tree_util.tree_leaves(x):
-            if hasattr(leaf, "block_until_ready"):
-                leaf.block_until_ready()
-        return x
 
 
 @dataclass
@@ -90,25 +75,14 @@ class ProfileSession:
 
     def __enter__(self) -> "ProfileSession":
         if self.logdir:
-            import jax
-
-            try:
-                jax.profiler.start_trace(self.logdir)
-                self._tracing = True
-            except Exception as e:  # backend without profiler support
-                log.warning("profiler_unavailable", error=str(e),
-                            logdir=self.logdir)
+            jax.profiler.start_trace(self.logdir)
+            self._tracing = True
         return self
 
     def __exit__(self, *exc):
         if self._tracing:
-            import jax
-
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:
-                log.warning("profiler_stop_failed", error=str(e))
             self._tracing = False
+            jax.profiler.stop_trace()
         return False
 
     # -- the wall-timing harness --------------------------------------------
@@ -117,7 +91,7 @@ class ProfileSession:
         """Call ``fn`` and block until its outputs are ready; returns
         ``(out, t0, t1)`` on ``time.monotonic()``."""
         t0 = time.monotonic()
-        out = _block_until_ready(fn(*args, **kwargs))
+        out = jax.block_until_ready(fn(*args, **kwargs))
         return out, t0, time.monotonic()
 
     def record(self, name: str, modeled_s: float, measured_s: float,

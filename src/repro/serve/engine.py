@@ -201,8 +201,8 @@ class ServeEngine:
 
         The template load needs an arch before it can build shapes, so the
         restore is two-phase: peek at the npz's ``__meta__`` for the arch
-        name, rebuild the params template from the registry, then do the
-        real shape/dtype-checked load.
+        name and depth cut (``layers``), rebuild the params template from
+        the registry, then do the real shape/dtype-checked load.
         """
         import json
         import os
@@ -219,7 +219,8 @@ class ServeEngine:
             raise ValueError(
                 f"{path}: checkpoint meta has no 'arch' key — was it "
                 "written by launch/train.py --ckpt-out?")
-        cfg = get_arch(meta["arch"], smoke=bool(meta.get("smoke", False)))
+        cfg = get_arch(meta["arch"], smoke=bool(meta.get("smoke", False)),
+                       layers=meta.get("layers"))
         template = TF.init_params_shape(cfg)
         params, meta = load_checkpoint(directory, template, step=s)
         params = jax.tree.map(jnp.asarray, params)

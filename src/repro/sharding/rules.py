@@ -21,16 +21,6 @@ MODEL_AXIS = "model"
 POD_AXIS = "pod"
 
 
-def _active_axis_names():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if mesh is None or mesh.empty:
-        return None
-    return set(mesh.axis_names)
-
-
 def _filter(entry, names):
     if entry is None:
         return None
@@ -38,16 +28,6 @@ def _filter(entry, names):
         kept = tuple(a for a in entry if a in names)
         return kept if kept else None
     return entry if entry in names else None
-
-
-def _axis_sizes():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if mesh is None or mesh.empty:
-        return None
-    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def shard(x, *spec):
@@ -60,9 +40,10 @@ def shard(x, *spec):
       spec would trigger XLA's "involuntary full rematerialization"; leaving
       it unconstrained lets propagation pick a feasible layout instead.
     """
-    sizes = _axis_sizes()
-    if not sizes:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     names = set(sizes)
     fspec = tuple(_filter(e, names) for e in spec)
     for dim, entry in zip(x.shape, fspec):

@@ -181,6 +181,25 @@ def test_quantize_kernel_matches_ref(bits, size):
     assert int(jnp.max(jnp.abs(q_ref.astype(jnp.int32)))) <= qmax
 
 
+def test_uniform_from_bits_equals_the_direct_cast():
+    """The kernel's int32-only bits -> U[0,1) conversion (the TPU kernel
+    compiler has no uint32 -> f32 cast) is the direct cast bit for bit, so
+    no int8 trajectory moved with it."""
+    from repro.kernels.quantize.ref import uniform_from_bits
+
+    edges = jnp.array([0, 1, 0xFFFF, 0x10000, 0xFFFFFF, 0x1000001,
+                       0x1000003, 0x7FFFFFFF, 0x80000000, 0x80000080,
+                       0x80000180, 0xFFFFFF7F, 0xFFFFFF80, 0xFFFFFFFF],
+                      jnp.uint32)
+    rand = jax.random.bits(jax.random.key(0), (1 << 20,), jnp.uint32)
+    for b in (edges, rand):
+        want = np.asarray(b.astype(jnp.float32) * (1.0 / 4294967296.0))
+        for view in (b, jax.lax.bitcast_convert_type(b, jnp.int32)):
+            got = np.asarray(jax.jit(uniform_from_bits)(view))
+            np.testing.assert_array_equal(got.view(np.uint32),
+                                          want.view(np.uint32))
+
+
 @pytest.mark.parametrize("bits", [8, 4])
 def test_dequant_mean_kernel_matches_ref(bits):
     N, M = 5, 3000

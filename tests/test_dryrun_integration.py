@@ -18,13 +18,13 @@ from repro.configs import get_arch, SHAPES
 from repro.core import local_sgd as LS
 from repro.launch import specs as SP
 from repro.launch import hlo_analysis as H
-from repro.launch.mesh import _make_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 
-mesh = _make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 cfg = get_arch("@ARCH@", smoke=True)
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=128, global_batch=8)
 state, batch, st_sh, b_sh, ca = SP.train_specs(cfg, shape, mesh)
-with mesh_context(mesh):
+with jax.sharding.set_mesh(mesh):
     local_step, sync_step, _ = LS.build_train_steps(cfg, mesh, client_axis=ca,
                                                     microbatch=2)
     cl = jax.jit(local_step, in_shardings=(st_sh, b_sh, None),

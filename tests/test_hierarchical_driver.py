@@ -316,7 +316,7 @@ import jax, jax.numpy as jnp, json
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import local_sgd as LS
 from repro.launch import hlo_analysis as H
-from repro.launch.mesh import make_host_pod_mesh, mesh_context
+from repro.launch.mesh import make_host_pod_mesh
 
 mesh = make_host_pod_mesh(pods=2, data=2, model=2)
 C = 4
@@ -332,7 +332,7 @@ st_sh = {"params": jax.tree.map(lambda _: rep, params),
          "step": NamedSharding(mesh, P())}
 shape_d = dict(zip(mesh.axis_names, mesh.devices.shape))
 out = {}
-with mesh_context(mesh):
+with jax.sharding.set_mesh(mesh):
     for name, step in [
             ("flat", LS.build_sync_step(None)),
             ("hier", LS.build_sync_step(None, hierarchical=True, n_pods=2,
@@ -352,7 +352,7 @@ import dataclasses, jax, json
 from repro.configs import get_arch, SHAPES
 from repro.core import local_sgd as LS
 from repro.launch import hlo_analysis as H
-from repro.launch.mesh import make_host_pod_mesh, mesh_context
+from repro.launch.mesh import make_host_pod_mesh
 from repro.launch.specs import train_specs
 
 mesh = make_host_pod_mesh(pods=2, data=2, model=2)
@@ -360,7 +360,7 @@ cfg = get_arch("qwen3-14b", smoke=True)
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=8)
 state, batch, st_sh, b_sh, ca = train_specs(cfg, shape, mesh)
 assert tuple(ca) == ("pod", "data"), ca
-with mesh_context(mesh):
+with jax.sharding.set_mesh(mesh):
     local_step, sync_step, _ = LS.build_train_steps(
         cfg, mesh, client_axis=ca, microbatch=1, inter_reducer="int8")
     assert sync_step.hierarchical and sync_step.n_pods == 2

@@ -368,6 +368,20 @@ def test_profile_wrap_preserves_sync_step_tags():
     assert sync_step_tags(wrapped)["reducer"] is not None
 
 
+def test_profile_session_raises_when_profiler_cannot_start(tmp_path):
+    """A trace session that cannot start is an error, never silently a
+    run without its trace."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path / "a"))
+    try:
+        with pytest.raises(RuntimeError):
+            with ProfileSession(logdir=str(tmp_path / "b")):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+
+
 def test_profile_session_without_logdir_is_harmless():
     prof = ProfileSession()                             # no jax.profiler
     with prof:
