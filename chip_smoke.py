@@ -22,7 +22,9 @@ on the host CPU.
 
 Compile seconds are what XLA spent compiling (or reading the persistent
 compilation cache), from ``jax.monitoring``; step seconds are host seconds
-ended by ``jax.block_until_ready``. The last line of stdout is
+from a step's launch to ``jax.block_until_ready`` on its state, read from
+the launcher's span log (``--trace``): a local step's ``dispatch`` and
+``wait`` spans, a round's ``reduce`` span. The last line of stdout is
 ``{"ok": true, "device": {...}}``, printed only when every check passed.
 
   python chip_smoke.py
@@ -58,6 +60,7 @@ from repro.launch import serve as serve_cli  # noqa: E402
 from repro.launch import train as train_cli  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_client_mesh  # noqa: E402
+from repro.obs import WALL, read_jsonl  # noqa: E402
 
 ARCH, LAYERS, SEQ, MOMENTUM, ETA1 = "minicpm3-4b", 4, 2048, 0.9, 0.01
 OUT = ROOT / "artifacts" / "chip_smoke"
@@ -135,14 +138,26 @@ def replicas_identical(tree):
     return jnp.all(jnp.stack([leaf(x) for x in jax.tree.leaves(tree)]))
 
 
+def step_seconds(spans):
+    """Host seconds of each local step (its ``dispatch`` and ``wait``
+    spans) and of each round (its ``reduce`` span), from a span log."""
+    wall = [s for s in spans if s.clock == WALL]
+    steps = {s.id: 0.0 for s in wall if s.name == "step"}
+    for s in wall:
+        if s.parent in steps and s.name in ("dispatch", "wait"):
+            steps[s.parent] += s.duration
+    return (list(steps.values()),
+            [s.duration for s in wall if s.name == "reduce"])
+
+
 def run_train(clock, argv, phase):
     """``launch.train.main`` with timings and losses pulled from its
-    stage results."""
+    stage results and span log."""
+    trace = OUT / f"{phase}.trace.json"
     c0, h0, t0 = clock.s, clock.cache_hits, time.perf_counter()
-    ds = train_cli.main(argv)
+    ds = train_cli.main(argv + ["--trace", str(trace)])
     wall = time.perf_counter() - t0
-    steps = [x for r in ds.results for x in r.step_s]
-    syncs = [x for r in ds.results for x in r.sync_s]
+    steps, syncs = step_seconds(read_jsonl(f"{trace}l"))
     losses = [x for r in ds.results for x in r.losses]
     say(phase, k_per_stage=[r.k for r in ds.results],
         rounds_per_stage=[r.rounds for r in ds.results],

@@ -170,34 +170,41 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
         # exists)
 
     def sync_step(state):
+        # named scopes "reduce" and "broadcast" name the round's two halves
+        # in a profile's op metadata
         n = jax.tree.leaves(state["params"])[0].shape[0]
-        opt = tree_broadcast_leading(tree_mean_leading(state["opt"]), n)
         rng = jax.random.fold_in(jax.random.key(base_seed), state["step"])
-        if dense and not streaming:
-            params = tree_broadcast_leading(
-                tree_mean_leading(state["params"]), n)
-            out = dict(state, params=params, opt=opt)
-        elif dense:
-            # streaming dense round: per-leaf mean + per-leaf rebroadcast
-            # inside the same reversed loop (state tree untouched, like
-            # the blocking dense round; rng unused) — leaf l's reduce and
-            # downlink broadcast form one data-independent unit under jit
-            params, _ = reduce_streaming(reducer, state["params"], None,
-                                         rng, broadcast_n=n)
-            out = dict(state, params=params, opt=opt)
-        else:
-            comm = state.get("comm")
-            if comm is None:
-                comm = reducer.init_state(state["params"])
-            if streaming:
-                params, comm = reduce_streaming(reducer, state["params"],
-                                                comm, rng, broadcast_n=n)
+        comm = None
+        with jax.named_scope("reduce"):
+            opt = tree_mean_leading(state["opt"])
+            if dense and not streaming:
+                params = tree_mean_leading(state["params"])
+            elif dense:
+                # streaming dense round: per-leaf mean + per-leaf
+                # rebroadcast inside the same reversed loop (state tree
+                # untouched, like the blocking dense round; rng unused) —
+                # leaf l's reduce and downlink broadcast form one
+                # data-independent unit under jit
+                params, _ = reduce_streaming(reducer, state["params"], None,
+                                             rng, broadcast_n=n)
             else:
-                consensus, comm = reducer.reduce(state["params"], comm, rng)
-                params = tree_broadcast_leading(consensus, n)
-            out = dict(state, params=params, opt=opt, comm=comm)
-        out.update(_client_sharded({"params": out["params"],
-                                    "opt": out["opt"]}, mesh, client_axis))
+                comm = state.get("comm")
+                if comm is None:
+                    comm = reducer.init_state(state["params"])
+                if streaming:
+                    params, comm = reduce_streaming(
+                        reducer, state["params"], comm, rng, broadcast_n=n)
+                else:
+                    params, comm = reducer.reduce(state["params"], comm, rng)
+        with jax.named_scope("broadcast"):
+            out = dict(state, opt=tree_broadcast_leading(opt, n),
+                       params=(params if streaming
+                               else tree_broadcast_leading(params, n)))
+            if comm is not None:
+                out["comm"] = comm
+            out.update(_client_sharded({"params": out["params"],
+                                        "opt": out["opt"]}, mesh,
+                                       client_axis))
         return out
 
     # tag the step with its reducer (and round structure) so
@@ -241,21 +248,25 @@ def _build_two_level_sync_step(intra, n_pods: int, inter_reducer,
             # concrete at trace time — same contract as Hierarchical
             raise ValueError(
                 f"{n} client replicas not divisible into {n_pods} pods")
-        opt = tree_broadcast_leading(tree_mean_leading(state["opt"]), n)
         rng = jax.random.fold_in(jax.random.key(base_seed), state["step"])
-        if topo.all_dense:
-            consensus, _ = topo.reduce(state["params"], None, rng)
-            out = dict(state,
-                       params=tree_broadcast_leading(consensus, n), opt=opt)
-        else:
-            comm = state.get("comm")
-            if comm is None:
-                comm = topo.init_state(state["params"])
-            consensus, comm = topo.reduce(state["params"], comm, rng)
+        comm = None
+        with jax.named_scope("reduce"):
+            opt = tree_mean_leading(state["opt"])
+            if topo.all_dense:
+                consensus, _ = topo.reduce(state["params"], None, rng)
+            else:
+                comm = state.get("comm")
+                if comm is None:
+                    comm = topo.init_state(state["params"])
+                consensus, comm = topo.reduce(state["params"], comm, rng)
+        with jax.named_scope("broadcast"):
             out = dict(state, params=tree_broadcast_leading(consensus, n),
-                       opt=opt, comm=comm)
-        out.update(_client_sharded({"params": out["params"],
-                                    "opt": out["opt"]}, mesh, client_axis))
+                       opt=tree_broadcast_leading(opt, n))
+            if comm is not None:
+                out["comm"] = comm
+            out.update(_client_sharded({"params": out["params"],
+                                        "opt": out["opt"]}, mesh,
+                                       client_axis))
         return out
 
     # tags: the driver prices the topology the round actually executes
@@ -346,9 +357,15 @@ def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
         n_pods = dict(zip(mesh.axis_names, mesh.devices.shape))["pod"]
     opt_init, opt_update = make_optimizer(optimizer, momentum, weight_decay)
 
+    def scoped_loss(params, batch):
+        # op metadata only: the forward's ops read loss/..., the backward's
+        # transpose(jvp(loss))/... in a profile
+        with jax.named_scope("loss"):
+            return loss_fn(params, cfg, batch)
+
     def per_client_grad(params, batch):
         if microbatch == 1:
-            return jax.value_and_grad(lambda p: loss_fn(p, cfg, batch))(params)
+            return jax.value_and_grad(lambda p: scoped_loss(p, batch))(params)
 
         def slice_mb(x, i):
             mb = x.shape[0] // microbatch
@@ -357,7 +374,7 @@ def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
         def body(carry, i):
             loss_acc, g_acc = carry
             mb = jax.tree.map(lambda x: slice_mb(x, i), batch)
-            loss, g = jax.value_and_grad(lambda p: loss_fn(p, cfg, mb))(params)
+            loss, g = jax.value_and_grad(lambda p: scoped_loss(p, mb))(params)
             return (loss_acc + loss, jax.tree.map(jnp.add, g_acc, g)), None
 
         zero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -383,7 +400,8 @@ def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
             # SyncSGD baseline: all-reduce grads over the client axis.
             grads = jax.lax.pmean(grads, axis_name="clients")
             loss = jax.lax.pmean(loss, axis_name="clients")
-        params, opt_state = opt_update(params, grads, opt_state, eta)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt_update(params, grads, opt_state, eta)
         return params, opt_state, loss
 
     vstep = jax.vmap(per_client_step, in_axes=(0, 0, 0, None),

@@ -15,7 +15,6 @@ steps.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional
 
@@ -88,11 +87,6 @@ class StageResult:
     rounds: int
     mean_loss: float
     losses: List[float] = field(default_factory=list)   # per local step
-    # host seconds per local step / per sync round, each ended by
-    # jax.block_until_ready on the new state (the first of each includes
-    # its compile)
-    step_s: List[float] = field(default_factory=list)
-    sync_s: List[float] = field(default_factory=list)
 
 
 @dataclass
@@ -128,55 +122,69 @@ class DriverBackend:
         engine.set_cost_basis(template, n_clients)
 
     def run_stage(self, stage, engine: Engine) -> StageStatus:
+        """One stage of local steps and rounds. Each place the loop takes
+        a batch, launches a program, blocks on the device or reads a value
+        back from it has a wall span of its own (``input``, ``dispatch``,
+        ``wait``, ``loss_read``), so a profile puts every device idle gap
+        down to one of them, and counts the host syncs."""
         drv, ds = self.driver, self.ds
         if drv.uses_center:
             ds.center = tree_mean_leading(ds.state["params"])
-        losses, step_s, sync_s = [], [], []
+        losses = []
         status = StageStatus()
         done = 0
-        tracer = engine.tracer
+        span = engine.tracer.span
         while done < stage.T:
             burst = min(stage.k, stage.T - done)
-            with tracer.span("local_steps", cat=CAT_COMPUTE, track="driver",
-                             attrs={"s": stage.s, "steps": burst,
-                                    "eta": stage.eta}):
+            with span("local_steps", cat=CAT_COMPUTE, track="driver",
+                      attrs={"s": stage.s, "steps": burst, "eta": stage.eta}):
                 for _ in range(burst):
-                    batch = next(self.it)
-                    t0 = time.perf_counter()
-                    if drv.uses_center:
-                        ds.state, m = drv.train_step(ds.state, batch,
-                                                     stage.eta, ds.center)
-                    else:
-                        ds.state, m = drv.train_step(ds.state, batch,
-                                                     stage.eta)
-                    jax.block_until_ready(ds.state)
-                    step_s.append(time.perf_counter() - t0)
-                    losses.append(float(m["loss"]))
+                    with span("step", cat=CAT_COMPUTE, track="driver",
+                              step_num=ds.iters_total):
+                        with span("input", track="driver"):
+                            batch = next(self.it)
+                        with span("dispatch", cat=CAT_COMPUTE,
+                                  track="driver"):
+                            if drv.uses_center:
+                                ds.state, m = drv.train_step(
+                                    ds.state, batch, stage.eta, ds.center)
+                            else:
+                                ds.state, m = drv.train_step(
+                                    ds.state, batch, stage.eta)
+                        with span("wait", track="driver"):
+                            jax.block_until_ready(ds.state)
+                        with span("loss_read", track="driver"):
+                            losses.append(float(m["loss"]))
                     done += 1
                     ds.iters_total += 1
                     if self.max_iters and ds.iters_total >= self.max_iters:
                         break
-            with tracer.span("reduce", cat=CAT_COMM, track="driver",
-                             attrs=dict(drv.span_attrs, s=stage.s)):
-                t0 = time.perf_counter()
-                ds.state = jax.block_until_ready(drv.sync_step(ds.state))
-                sync_s.append(time.perf_counter() - t0)
+            with span("reduce", cat=CAT_COMM, track="driver",
+                      attrs=dict(drv.span_attrs, s=stage.s)):
+                with span("dispatch", cat=CAT_COMM, track="driver"):
+                    ds.state = drv.sync_step(ds.state)
+                with span("wait", track="driver"):
+                    jax.block_until_ready(ds.state)
             status.rounds += 1
             ds.rounds_total += 1
             if self.max_iters and ds.iters_total >= self.max_iters:
                 status.stop = True
                 break
         status.iters = done
-        res = StageResult(stage.s, stage.eta, stage.k, done, status.rounds,
-                          float(jnp.mean(jnp.asarray(losses))) if losses
-                          else float("nan"), losses, step_s, sync_s)
-        ds.results.append(res)
-        engine.metrics.gauge(
-            "train.stage_objective", unit="loss",
-            help="mean training loss per stage").set(res.mean_loss,
-                                                     stage=res.stage)
-        log.info("stage_done", stage=res.stage, eta=res.eta, k=res.k,
-                 iters=res.iters, rounds=res.rounds, loss=res.mean_loss)
+        with span("stage_end", track="driver"):
+            mean_loss = float("nan")
+            if losses:
+                with span("loss_read", track="driver"):
+                    mean_loss = float(jnp.mean(jnp.asarray(losses)))
+            res = StageResult(stage.s, stage.eta, stage.k, done,
+                              status.rounds, mean_loss, losses)
+            ds.results.append(res)
+            engine.metrics.gauge(
+                "train.stage_objective", unit="loss",
+                help="mean training loss per stage").set(res.mean_loss,
+                                                         stage=res.stage)
+            log.info("stage_done", stage=res.stage, eta=res.eta, k=res.k,
+                     iters=res.iters, rounds=res.rounds, loss=res.mean_loss)
         return status
 
     def finish(self, engine: Engine) -> DriverState:

@@ -218,8 +218,6 @@ def main(argv=None):
         log.info("  stage %d: k=%d rounds=%d loss=%.4f", r.stage, r.k,
                  r.rounds, r.mean_loss)
         log.info("    losses=%s", [round(x, 4) for x in r.losses])
-        log.info("    step_s=%s sync_s=%s", [round(x, 4) for x in r.step_s],
-                 [round(x, 4) for x in r.sync_s])
     if profile is not None:
         from repro.obs import format_skew_table
         profile.emit_spans(tracer)

@@ -27,6 +27,7 @@ from repro.launch import serve as serve_cli
 from repro.launch import train as train_cli
 from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
 from repro.launch.mesh import make_client_mesh
+from repro.obs import WALL, read_jsonl
 from repro.serve import SchedulerConfig, ServeEngine
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -59,14 +60,21 @@ def test_train_main_layers_placement_and_ckpt_meta(tmp_path,
         "--arch", "qwen3-14b", "--smoke", "--layers", "1",
         "--clients", "2", "--batch", "1", "--seq", "16", "--momentum",
         "0.9", "--steps", "4", "--k1", "2", "--T1", "2", "--stages", "2",
-        "--ckpt-out", str(ckpt)])
+        "--ckpt-out", str(ckpt), "--trace", str(tmp_path / "t.json")])
     assert jax.config.jax_compilation_cache_dir == str(cache_dir_restored)
     assert [r.k for r in ds.results] == [2, 4]
     assert ds.iters_total == 4
-    for r in ds.results:
-        assert len(r.losses) == len(r.step_s) == r.iters
-        assert len(r.sync_s) == r.rounds
-        assert all(np.isfinite(r.losses)) and min(r.step_s) > 0
+    spans = [s for s in read_jsonl(str(tmp_path / "t.jsonl"))
+             if s.clock == WALL]
+    stages = [s for s in spans if s.name == "stage"]
+    assert len(stages) == len(ds.results)
+    for st, r in zip(stages, ds.results):
+        inside = [s for s in spans if st.t0 <= s.t0 and s.t1 <= st.t1]
+        steps = [s for s in inside if s.name == "step"]
+        assert len(r.losses) == len(steps) == r.iters
+        assert len([s for s in inside if s.name == "reduce"]) == r.rounds
+        assert all(np.isfinite(r.losses))
+        assert min(s.duration for s in steps) > 0
     # one layer at smoke widths, replicas on the device mesh's data axis
     cfg = get_arch("qwen3-14b", smoke=True, layers=1)
     want = LS.init_state_shape(cfg, 2)

@@ -98,6 +98,47 @@ def test_null_tracer_is_falsy_and_noop():
     assert NULL_TRACER.spans == []
 
 
+def test_null_tracer_span_is_a_profiler_annotation_only():
+    from jax.profiler import TraceAnnotation
+
+    sp = NULL_TRACER.span("step", attrs={"s": 1, "tree": [1]}, step_num=3)
+    assert isinstance(sp, TraceAnnotation)
+    with sp:
+        sp.set(rounds=2, skipped=None)
+    with NULL_TRACER.span("stage", attrs={"s": 1}) as sp:
+        sp.set(rounds=3)
+    assert NULL_TRACER.spans == []
+
+
+def test_wall_spans_reach_the_profiler_and_others_do_not(tmp_path):
+    """Wall spans of a Tracer and of NULL_TRACER become ``stl.*``
+    annotations on the profiler's clock, with their scalar attributes;
+    spans on the virtual and modeled clocks never do."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.span("stage", attrs={"s": 2, "tree": [1]}) as sp:
+        with NULL_TRACER.span("step", step_num=7):
+            tr.add("reduce", 0.0, 1.0, clock=MODELED)
+            tr.instant("broadcast", 1.0)
+        sp.set(rounds=3)
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {ev.name: dict(ev.stats)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("stl.")}
+    assert set(events) == {"stl.stage", "stl.step"}
+    assert events["stl.stage"] == {"s": 2, "rounds": 3}
+    assert events["stl.step"]["step_num"] == 7
+    assert [s.name for s in tr.spans] == ["stage", "reduce", "broadcast"]
+    assert NULL_TRACER.spans == []
+
+
 def test_untraced_run_records_nothing(problem):
     loss_fn, eval_fn, p0, data = problem
     before = len(NULL_TRACER.spans)
