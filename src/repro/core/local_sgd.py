@@ -39,6 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.comm import get_reducer
 from repro.comm.reducer import DenseMean, reduce_streaming
 from repro.configs.base import ArchConfig
+from repro.kernels.flash_attention.causal import kernel_mesh
 from repro.models import transformer as TF
 from repro.optim import make_optimizer
 from repro.sharding import param_specs
@@ -409,7 +410,9 @@ def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
                      axis_name="clients")
 
     def train_step_local(state, batch, eta):
-        params, opt, loss = vstep(state["params"], state["opt"], batch, eta)
+        with kernel_mesh(mesh):
+            params, opt, loss = vstep(state["params"], state["opt"], batch,
+                                      eta)
         # dict(state, ...) so extra keys (e.g. a compressed sync_step's
         # "comm" error-feedback residuals) survive the local step.
         return dict(state, params=params, opt=opt, step=state["step"] + 1), {

@@ -1,7 +1,9 @@
 # Pallas TPU kernels for the compute hot-spots of the models the paper's
 # algorithm trains/serves (the paper's own contribution is a communication
 # schedule — kernel-free — so kernels/ serves the substrate):
-#   flash_attention/  blockwise online-softmax attention (causal/window/softcap/GQA)
+#   flash_attention/  blockwise online-softmax attention (causal/window/softcap/GQA);
+#                     causal.py routes MLA's training attention to JAX's own
+#                     splash kernel on TPU (the only one a model path calls)
 #   fused_update/     fused momentum-SGD update (Local SGD's k-per-round inner loop)
 #   quantize/         fused stochastic-round quantize + dequant-accumulate
 #                     (the compressed communication round, repro.comm)

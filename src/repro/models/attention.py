@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, AttentionConfig
+from repro.kernels.flash_attention.causal import block_size, causal_attention
 from repro.models.layers import apply_rope, dense_init, rms_norm, softcap
 from repro.sharding import shard
 
@@ -88,8 +89,8 @@ def attend(q, k, v, bias, cap: Optional[float], scale: float):
     For long sequences the q axis is processed in CHUNK_Q blocks under
     lax.scan (flash-style online softmax is unnecessary here since each block
     still sees all of K — the point is never materialising the full (Sq,Sk)
-    score tensor). The Pallas kernel (repro.kernels.flash_attention) is the
-    TPU-optimal version of the same contraction.
+    score tensor). MLA's training attention takes JAX's splash kernel on
+    TPU instead (``repro.kernels.flash_attention.causal``).
     """
     B, Sq, H, hd = q.shape
     if Sq > CHUNK_Q_THRESHOLD and Sq % CHUNK_Q == 0:
@@ -301,8 +302,14 @@ def apply_mla(params, att: AttentionConfig, x, pos_q, *, window, eps,
         v = shard(v, None, None, "model", None)
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, rd))], axis=-1)
-        bias = _mask_bias(pos_q, pos_q, window)
-        out = attend(q, k, v, bias, att.logit_softcap, scale)
+        xla = lambda q, k, v: attend(q, k, v, _mask_bias(pos_q, pos_q, window),
+                                     att.logit_softcap, scale)
+        if (cache is None and window is None and att.logit_softcap is None
+                and block_size(S) is not None):
+            # training / scoring: the fused kernel on TPU, xla elsewhere
+            out = causal_attention(q, k, v, xla, scale=scale)
+        else:
+            out = xla(q, k, v)
         new_cache = None
         if cache is not None:  # prefill: store latent tail
             new_cache = {"ckv": _write_tail(cache["ckv"], ckv),

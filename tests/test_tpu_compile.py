@@ -11,16 +11,19 @@ one process may load the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.core import local_sgd as LS
 from repro.kernels.fused_update.ops import sgd_update
 from repro.kernels.quantize import ops as Q
+from repro.obs import metrics as obs_metrics
 
 LEAF = 2560 * 6400           # MiniCPM3-4B's w_gate / w_up / w_down
 CLIENTS = 2
@@ -28,7 +31,7 @@ HBM_BYTES = 15.75e9          # one v5e chip's HBM as its compiler sees it
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -43,9 +46,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -89,3 +97,54 @@ def test_minicpm3_sync_step_fits_one_v5e(one_chip):
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert m.alias_size_in_bytes >= 0.99 * m.argument_size_in_bytes  # donated
     assert need <= HBM_BYTES, need
+
+
+# the local step with attention through `attend`'s f32 2048 x 2048
+# scores, compiled for the same chips: temp bytes per chip
+SCORES_LOCAL_STEP_TEMP = {1: 7_203_408_896, 4: 2_955_895_296}
+# what the fused kernel must save at least: 1.3 GB with two clients on a
+# chip, 1.0 GB with one (the score tensors it no longer writes)
+KERNEL_SAVES = {1: 1.3e9, 4: 1.0e9}
+
+
+@pytest.mark.parametrize("chips,clients", [(1, 2), (4, 4)])
+def test_minicpm3_local_step_attends_through_splash_kernel(topo, chips,
+                                                          clients):
+    """The benchmark's local step (MiniCPM3-l4, 1 x 2048 tokens a
+    client) lowers MLA's attention to the splash kernel: no 2048 x 2048
+    buffer is left, the temp bytes fall, and on four chips the kernel's
+    shard_map adds no collective (the one all-reduce is the scalar mean
+    loss, which the step had before the kernel too)."""
+    cfg = get_arch("minicpm3-4b", layers=4)
+    mesh = jax.make_mesh((chips, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=topo.devices[:chips])
+    local, _, _ = LS.build_train_steps(cfg, mesh, client_axis="data",
+                                       momentum=0.9)
+    shapes = LS.init_state_shape(cfg, clients)
+    shardings = LS.state_shardings(cfg, mesh, shapes["params"],
+                                   shapes["opt"])
+    state = jax.tree.map(lambda sh, x: _spec(x.shape, x.dtype, sh),
+                         shardings, shapes)
+    specs = LS.batch_spec(cfg, "data", False)
+    batch = {k: _spec((clients, 1, 2048), jnp.int32,
+                      NamedSharding(mesh, specs[k]))
+             for k in ("tokens", "labels")}
+    eta = _spec((), jnp.float32, NamedSharding(mesh, P()))
+
+    reg = obs_metrics.registry()
+    before = (reg["attention.lowered"].value(path="kernel")
+              if "attention.lowered" in reg else 0.0)
+    compiled = jax.jit(local, donate_argnums=(0,)).lower(
+        state, batch, eta).compile()
+    assert reg["attention.lowered"].value(path="kernel") > before
+
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
+    assert not re.search(r",2048,2048\]", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= SCORES_LOCAL_STEP_TEMP[chips] - KERNEL_SAVES[chips], temp
+    assert "all-gather" not in text
+    reduces = re.findall(r"= (\S+) all-reduce\(", text)
+    assert reduces == ([] if chips == 1 else ["f32[]{:T(128)}"]), reduces
