@@ -32,16 +32,18 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
-from jax.extend.core import Primitive
-from jax.interpreters import ad, batching, mlir
 from jax.sharding import PartitionSpec as P
 
-from repro.obs import metrics as obs_metrics
+from repro.obs.lowered import lowering_counter
 
 _BLOCKS = (512, 256, 128)   # tile edges tried, largest first
 
 _MESH: contextvars.ContextVar = contextvars.ContextVar(
     "attention_kernel_mesh", default=None)
+
+_lowered = lowering_counter(
+    "attention.lowered",
+    help="full-sequence causal attentions lowered, by path")
 
 
 @contextlib.contextmanager
@@ -95,33 +97,3 @@ def causal_attention(q, k, v, xla: Callable, *, scale: float):
     return jax.lax.platform_dependent(
         q, k, v, tpu=kernel,
         default=lambda q, k, v: _lowered(xla(q, k, v), path="xla"))
-
-
-# ---------------------------------------------------------------------------
-# The counter: an identity that counts itself when it is lowered. Inside a
-# platform_dependent branch it is lowered only where that branch is.
-# ---------------------------------------------------------------------------
-
-_lowered_p = Primitive("attention_lowered")
-_lowered_p.def_impl(lambda x, *, path: x)
-_lowered_p.def_abstract_eval(lambda x, *, path: x)
-
-
-def _lowered(x, *, path: str):
-    return _lowered_p.bind(x, path=path)
-
-
-def _lowering(ctx, x, *, path):
-    obs_metrics.registry().counter(
-        "attention.lowered", unit="calls",
-        help="full-sequence causal attentions lowered, by path").inc(
-            path=path)
-    return [x]
-
-
-mlir.register_lowering(_lowered_p, _lowering)
-ad.primitive_jvps[_lowered_p] = (
-    lambda primals, tangents, *, path:
-    (_lowered(primals[0], path=path), tangents[0]))
-batching.primitive_batchers[_lowered_p] = (
-    lambda args, dims, *, path: (_lowered(args[0], path=path), dims[0]))

@@ -16,7 +16,10 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, SSMConfig
 from repro.models.layers import dense_init, rms_norm
+from repro.obs.lowered import lowering_counter
 from repro.sharding import shard
+
+_lowered = lowering_counter("ssd.lowered", help="chunked SSDs lowered, by chunk")
 
 
 def _dims(cfg: ArchConfig):
@@ -68,12 +71,17 @@ def _causal_conv(x, w, carry=None):
 
 def _segsum_exp(dA):
     """dA: (..., Q). Return exp(segsum) lower-tri matrix (..., Q, Q):
-    L[i,j] = exp(sum_{j<k<=i} dA_k) for i>=j else 0."""
+    L[i,j] = exp(sum_{j<k<=i} dA_k) for i>=j else 0.
+
+    Masked before the exponential: above the diagonal the segment sums
+    are positive (dA <= 0) and over a chunk of 256 pass float32's exp
+    range, and an ``inf`` there times the zero cotangent the mask gives
+    is NaN in the backward pass."""
     cs = jnp.cumsum(dA, axis=-1)
     diff = cs[..., :, None] - cs[..., None, :]
     Q = dA.shape[-1]
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    return jnp.where(tri, jnp.exp(diff), 0.0)
+    return jnp.exp(jnp.where(tri, diff, -jnp.inf))
 
 
 def ssd_chunked(xs, dt, A, Bc, Cc, chunk: int, initial_state=None):
@@ -81,64 +89,73 @@ def ssd_chunked(xs, dt, A, Bc, Cc, chunk: int, initial_state=None):
 
     xs: (B,S,H,P)  dt: (B,S,H)  A: (H,)  Bc,Cc: (B,S,G,N)
     Returns y (B,S,H,P), final_state (B,H,P,N). All math fp32.
+
+    The work sits under ``jax.named_scope("ssd")`` with one inner scope a
+    phase (``intra_chunk``, ``chunk_states``, ``inter_chunk_scan``,
+    ``read_out``), and each SSD lowered counts once under
+    ``ssd.lowered{chunk}``. Every exponent but the masked upper triangle
+    of ``_segsum_exp`` is a sum of dt·A <= 0 (A < 0, dt > 0), so no
+    ``exp`` here can overflow.
     """
     Bsz, S, H, P = xs.shape
     G, N = Bc.shape[2], Bc.shape[3]
     assert S % chunk == 0, (S, chunk)
+    if G > 1:
+        raise NotImplementedError("n_groups > 1 not needed by assigned archs")
     nc = S // chunk
     rep = H // G
 
-    xs = xs.astype(jnp.float32).reshape(Bsz, nc, chunk, H, P)
-    dt = dt.astype(jnp.float32).reshape(Bsz, nc, chunk, H)
-    Bc = Bc.astype(jnp.float32).reshape(Bsz, nc, chunk, G, N)
-    Cc = Cc.astype(jnp.float32).reshape(Bsz, nc, chunk, G, N)
+    with jax.named_scope("ssd"):
+        xs = xs.astype(jnp.float32).reshape(Bsz, nc, chunk, H, P)
+        dt = dt.astype(jnp.float32).reshape(Bsz, nc, chunk, H)
+        Bc = Bc.astype(jnp.float32).reshape(Bsz, nc, chunk, G, N)
+        Cc = Cc.astype(jnp.float32).reshape(Bsz, nc, chunk, G, N)
 
-    dA = dt * A[None, None, None, :]          # (B,nc,Q,H)
-    dAh = jnp.moveaxis(dA, -1, 2)             # (B,nc,H,Q)
-    L = _segsum_exp(dAh)                      # (B,nc,H,Q,Q)
-    xdt = xs * dt[..., None]                  # dt-weighted inputs
+        dA = dt * A[None, None, None, :]          # (B,nc,Q,H)
+        dAh = jnp.moveaxis(dA, -1, 2)             # (B,nc,H,Q)
+        xdt = xs * dt[..., None]                  # dt-weighted inputs
 
-    # intra-chunk (diagonal) term: "attention" C_i · B_j with decay L
-    CB = jnp.einsum("bnqgi,bnsgi->bngqs", Cc, Bc)      # (B,nc,G,Q,Q)
-    CB = jnp.repeat(CB, rep, axis=2)                   # (B,nc,H,Q,Q)
-    y_diag = jnp.einsum("bnhqs,bnshp->bnqhp", CB * L, xdt)
+        # intra-chunk (diagonal) term: "attention" C_i · B_j with decay L
+        with jax.named_scope("intra_chunk"):
+            L = _segsum_exp(dAh)                               # (B,nc,H,Q,Q)
+            CB = jnp.einsum("bnqgi,bnsgi->bngqs", Cc, Bc)      # (B,nc,G,Q,Q)
+            CB = jnp.repeat(CB, rep, axis=2)                   # (B,nc,H,Q,Q)
+            y_diag = jnp.einsum("bnhqs,bnshp->bnqhp", CB * L, xdt)
 
-    # per-chunk final states: sum_j decay_to_end_j * B_j x_j
-    seg_end = jnp.exp(jnp.cumsum(dAh, axis=-1)[..., -1:] - jnp.cumsum(dAh, axis=-1))  # (B,nc,H,Q)
-    states = jnp.einsum(
-        "bnshp,bnsgi,bnhs->bnhpi", xdt, Bc, seg_end
-    )  # (B,nc,H,P,N) for G=1; general G via repeat
-    if G > 1:
-        # recompute honouring groups
-        Brep = jnp.repeat(Bc, rep, axis=3) if False else None  # G>1 handled below
-        raise NotImplementedError("n_groups > 1 not needed by assigned archs")
+        # per-chunk final states: sum_j decay_to_end_j * B_j x_j
+        with jax.named_scope("chunk_states"):
+            seg_end = jnp.exp(jnp.cumsum(dAh, axis=-1)[..., -1:] - jnp.cumsum(dAh, axis=-1))  # (B,nc,H,Q)
+            states = jnp.einsum(
+                "bnshp,bnsgi,bnhs->bnhpi", xdt, Bc, seg_end
+            )  # (B,nc,H,P,N) for G=1
 
-    # inter-chunk recurrence over chunk states
-    chunk_decay = jnp.exp(jnp.sum(dAh, axis=-1))  # (B,nc,H)
+        # inter-chunk recurrence over chunk states
+        with jax.named_scope("inter_chunk_scan"):
+            chunk_decay = jnp.exp(jnp.sum(dAh, axis=-1))  # (B,nc,H)
 
-    def step(carry, inp):
-        st, dec = inp
-        new = carry * dec[..., None, None] + st
-        return new, carry  # emit state *entering* the chunk
+            def step(carry, inp):
+                st, dec = inp
+                new = carry * dec[..., None, None] + st
+                return new, carry  # emit state *entering* the chunk
 
-    init = (
-        jnp.zeros((Bsz, H, P, N), jnp.float32)
-        if initial_state is None
-        else initial_state.astype(jnp.float32)
-    )
-    final_state, prev_states = jax.lax.scan(
-        step,
-        init,
-        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)),
-    )
-    prev_states = jnp.moveaxis(prev_states, 0, 1)  # (B,nc,H,P,N)
+            init = (
+                jnp.zeros((Bsz, H, P, N), jnp.float32)
+                if initial_state is None
+                else initial_state.astype(jnp.float32)
+            )
+            final_state, prev_states = jax.lax.scan(
+                step,
+                init,
+                (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)),
+            )
+            prev_states = jnp.moveaxis(prev_states, 0, 1)  # (B,nc,H,P,N)
 
-    # off-diagonal contribution: C_i · (decay_from_start_i * state_in)
-    seg_start = jnp.exp(jnp.cumsum(dAh, axis=-1))  # decay from chunk start to i (inclusive)
-    y_off = jnp.einsum("bnqgi,bnhpi,bnhq->bnqhp", Cc, prev_states, seg_start)
-
-    y = (y_diag + y_off).reshape(Bsz, S, H, P)
-    return y, final_state
+        # off-diagonal contribution: C_i · (decay_from_start_i * state_in)
+        with jax.named_scope("read_out"):
+            seg_start = jnp.exp(jnp.cumsum(dAh, axis=-1))  # decay from chunk start to i (inclusive)
+            y_off = jnp.einsum("bnqgi,bnhpi,bnhq->bnqhp", Cc, prev_states, seg_start)
+            y = (y_diag + y_off).reshape(Bsz, S, H, P)
+    return _lowered(y, chunk=chunk), final_state
 
 
 def apply_mamba2(params, cfg: ArchConfig, x, cache=None):
