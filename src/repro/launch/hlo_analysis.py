@@ -6,9 +6,10 @@ reduce-scatter / all-to-all / collective-permute op contributes its tensor
 bytes, attributed to the mesh axes its replica groups span (this is how we
 separate the paper's client-axis traffic from tensor-parallel traffic).
 
-Link-traffic factors (ring algorithms, large N): all-reduce moves ≈2× its
-bytes over the busiest link; all-gather / reduce-scatter ≈1× the full tensor;
-all-to-all ≈1×(N-1)/N; collective-permute 1×.
+Link-traffic factors (ring algorithms, N devices): all-reduce moves
+2×(N-1)/N of its bytes over the busiest link; all-gather / reduce-scatter
+(N-1)/N of the full tensor (a reduce-scatter's output is one shard of it);
+all-to-all (N-1)/N; collective-permute 1×.
 """
 from __future__ import annotations
 
@@ -33,9 +34,6 @@ _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{(.*?)\}\s*[,)]")
 _GROUPS_IOTA_RE = re.compile(
     r"replica_groups=\[(\d+),(\d+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?")
-
-_FACTORS = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
-            "all-to-all": 1.0, "collective-permute": 1.0}
 
 
 def _shape_bytes(type_str: str) -> int:
@@ -113,7 +111,8 @@ def parse_collectives(hlo_text: str, mesh_shape: Dict[str, int]) -> List[dict]:
         group = _first_group(line, n_devices)
         axes = _axes_of_group(group, mesh_shape) if group else ("unknown",)
         n = len(group) if group else 1
-        factor = _FACTORS[kind]
+        if kind == "reduce-scatter":
+            nbytes *= n   # its output is one shard; the full tensor is n
         if kind == "all-reduce":
             link = 2.0 * nbytes * (n - 1) / max(n, 1)
         elif kind in ("all-gather", "reduce-scatter"):

@@ -1,3 +1,5 @@
-from repro.sharding.rules import shard, param_specs, DATA_AXIS, MODEL_AXIS, POD_AXIS
+from repro.sharding.rules import (shard, param_specs, scatter_dim, DATA_AXIS,
+                                  MODEL_AXIS, POD_AXIS)
 
-__all__ = ["shard", "param_specs", "DATA_AXIS", "MODEL_AXIS", "POD_AXIS"]
+__all__ = ["shard", "param_specs", "scatter_dim", "DATA_AXIS", "MODEL_AXIS",
+           "POD_AXIS"]

@@ -208,6 +208,28 @@ def feasible_specs(specs, shapes, mesh):
                         is_leaf=lambda s: isinstance(s, P))
 
 
+def scatter_dim(shape, n: int) -> Optional[int]:
+    """The dimension of an array to reduce-scatter over ``n`` devices, or
+    None where the TPU compiler would not emit a reduce-scatter.
+
+    A float32 array is tiled (8, 128) over its last two dimensions, and a
+    shard the compiler cannot lay out in whole tiles falls back to an
+    all-reduce and a slice: so the rows must fill a tile, and the leading
+    (untiled) dimension is scattered where it divides by ``n`` (the layer
+    axis of a stacked leaf), else the columns where each shard keeps whole
+    128-lane tiles (an embedding's width). A 2-D array's rows are never
+    scattered: the compiler pads them and falls back too.
+    """
+    if len(shape) < 2 or shape[-2] < 8:
+        return None
+    for d in range(len(shape) - 2):
+        if shape[d] % n == 0:
+            return d
+    if shape[-1] % (128 * n) == 0:
+        return len(shape) - 1
+    return None
+
+
 # ---------------------------------------------------------------------------
 # KV / recurrent cache specs (serving)
 # ---------------------------------------------------------------------------

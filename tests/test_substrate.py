@@ -127,3 +127,20 @@ ENTRY %main (p: f32[8,16]) -> f32[8,16] {
     assert ag["trip_mult"] == 1.0
     assert ag["axes"] == ["data"]          # groups {0,2} vary the major axis
     assert ar["bytes"] == 8 * 16 * 4 * 5
+
+
+def test_hlo_parser_prices_reduce_scatter_by_its_full_tensor():
+    """A reduce-scatter's result is one shard: its ring moves (n-1)/n of
+    the n-shard tensor, as the all-gather that undoes it does, and the
+    pair moves what one all-reduce of that tensor moves."""
+    from repro.launch.hlo_analysis import parse_collectives
+
+    hlo = """
+  %rs = f32[2,16]{1,0} reduce-scatter(%x), replica_groups={{0,1,2,3}}, dimensions={0}, to_apply=%add
+  %ag = f32[8,16]{1,0} all-gather(%rs), replica_groups={{0,1,2,3}}, dimensions={0}
+  %ar = f32[8,16]{1,0} all-reduce(%x), replica_groups={{0,1,2,3}}, to_apply=%add
+"""
+    rs, ag, ar = parse_collectives(hlo, {"data": 4})
+    assert rs["bytes"] == ag["bytes"] == 8 * 16 * 4
+    assert rs["link_bytes"] == ag["link_bytes"] == 8 * 16 * 4 * 3 / 4
+    assert rs["link_bytes"] + ag["link_bytes"] == ar["link_bytes"]

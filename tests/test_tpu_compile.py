@@ -24,6 +24,7 @@ from repro.core import local_sgd as LS
 from repro.kernels.fused_update.ops import sgd_update
 from repro.kernels.quantize import ops as Q
 from repro.obs import metrics as obs_metrics
+from repro.sharding import scatter_dim
 
 LEAF = 2560 * 6400           # MiniCPM3-4B's w_gate / w_up / w_down
 CLIENTS = 2
@@ -107,19 +108,26 @@ SCORES_LOCAL_STEP_TEMP = {1: 7_203_408_896, 4: 2_955_895_296}
 KERNEL_SAVES = {1: 1.3e9, 4: 1.0e9}
 
 
-def _compile_local_step(topo, cfg, chips, clients):
-    """The benchmark's local step (1 x 2048 tokens a client), its state
-    donated, compiled for ``chips`` described v5e chips."""
+def _bench_steps(topo, cfg, chips, clients):
+    """The benchmark's mesh, its two steps and the state's shapes in its
+    shardings, on ``chips`` described v5e chips."""
     mesh = jax.make_mesh((chips, 1), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2,
                          devices=topo.devices[:chips])
-    local, _, _ = LS.build_train_steps(cfg, mesh, client_axis="data",
-                                       momentum=0.9)
+    local, sync, _ = LS.build_train_steps(cfg, mesh, client_axis="data",
+                                          momentum=0.9)
     shapes = LS.init_state_shape(cfg, clients)
     shardings = LS.state_shardings(cfg, mesh, shapes["params"],
                                    shapes["opt"])
     state = jax.tree.map(lambda sh, x: _spec(x.shape, x.dtype, sh),
                          shardings, shapes)
+    return mesh, local, sync, state
+
+
+def _compile_local_step(topo, cfg, chips, clients):
+    """The benchmark's local step (1 x 2048 tokens a client), its state
+    donated, compiled for ``chips`` described v5e chips."""
+    mesh, local, _, state = _bench_steps(topo, cfg, chips, clients)
     specs = LS.batch_spec(cfg, "data", False)
     batch = {k: _spec((clients, 1, 2048), jnp.int32,
                       NamedSharding(mesh, specs[k]))
@@ -181,3 +189,98 @@ def test_mamba2_local_step_fits_one_v5e(topo):
     assert need <= HBM_BYTES, need
     assert abs(m.temp_size_in_bytes / MAMBA2_LOCAL_STEP_TEMP - 1) <= 0.05, \
         m.temp_size_in_bytes
+
+
+# the four-chip round as nine float32 all-reduces (before it was averaged
+# by reduce-scatter and all-gather): bytes a chip over the client axis by
+# `hlo_analysis`'s ring model, compiled with jax 0.9.0 and its libtpu
+ALL_REDUCE_ROUND_LINK_BYTES = 5_265_487_872
+
+
+def _lowered(path):
+    reg = obs_metrics.registry()
+    return (reg["sync.lowered"].value(path=path)
+            if "sync.lowered" in reg else 0.0)
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert m.alias_size_in_bytes >= 0.99 * m.argument_size_in_bytes  # donated
+    assert need <= HBM_BYTES, need
+
+
+def _typed(text, op):
+    """The result types of every ``op`` in an HLO text, as ``dtype[dims]``."""
+    return [f"{t}[{d}]" for t, d in
+            re.findall(rf"= (\w+)\[([\d,]*)\]\S* {op}\(", text)]
+
+
+def test_minicpm3_four_chip_round_gathers_in_leaf_dtype(topo):
+    """The benchmark's four-chip MiniCPM3-l4 round (one client a chip, the
+    state donated) averages its bfloat16 params by float32
+    reduce-scatters and bfloat16 all-gathers: no bfloat16 leaf but the
+    small norm scales is widened into an all-reduce (the float32 momentum
+    keeps it), every bfloat16 leaf that has a dimension to scatter is
+    gathered in bfloat16, the client axis carries at most 0.9 of the
+    all-reduce round's bytes, no copy of the state is added, and it fits
+    the chip."""
+    from repro.launch import hlo_analysis as H
+
+    cfg = get_arch("minicpm3-4b", layers=4)
+    before = _lowered("scatter_gather")
+    _, _, sync, state = _bench_steps(topo, cfg, 4, 4)
+    compiled = jax.jit(sync, donate_argnums=(0,)).lower(state).compile()
+    assert _lowered("scatter_gather") > before
+    _fits(compiled)
+
+    text = compiled.as_text()
+    # what is left on the all-reduce is the momentum and the norm scales
+    momentum = sum(x.size // x.shape[0] * x.dtype.itemsize
+                   for x in jax.tree.leaves(state["opt"]))  # one replica
+    reduced = sum(c["bytes"] for c in H.parse_collectives(
+        text, {"data": 4, "model": 1}) if c["kind"] == "all-reduce")
+    assert momentum <= reduced < momentum + 1e6, (reduced, momentum)
+    scattered = [x.shape[1:] for x in jax.tree.leaves(state["params"])
+                 if x.dtype == jnp.bfloat16
+                 and scatter_dim(x.shape[1:], 4) is not None]
+    assert len(scattered) == 10
+    gathered = _typed(text, "all-gather")
+    for shape in scattered:
+        assert f"bf16[{','.join(map(str, shape))}]" in gathered, shape
+    assert not _typed(text, "copy")
+    link = H.collective_summary(H.parse_collectives_nested(
+        text, {"data": 4, "model": 1}))["by_axes"]["data"]
+    assert link <= 0.9 * ALL_REDUCE_ROUND_LINK_BYTES, link
+
+
+def _ops(text):
+    """Each instruction of an HLO text as its result type and opcode."""
+    return sorted(re.findall(r"= (\S+) ([a-z][\w\-]*)\(", text))
+
+
+def test_minicpm3_one_chip_round_keeps_the_all_reduce_mean(topo):
+    """The benchmark's one-chip round (two clients sharing the chip) is
+    not touched: it counts ``all_reduce``, has no collective, and
+    compiles to the ops of ``tree_mean_leading`` + ``tree_broadcast_leading``
+    laid out over the client axis, the round it was before."""
+    from repro.utils.tree import tree_broadcast_leading, tree_mean_leading
+
+    cfg = get_arch("minicpm3-4b", layers=4)
+    before = _lowered("all_reduce")
+    mesh, _, sync, state = _bench_steps(topo, cfg, 1, CLIENTS)
+    compiled = jax.jit(sync, donate_argnums=(0,)).lower(state).compile()
+    assert _lowered("all_reduce") > before
+    _fits(compiled)
+
+    def mean_round(s):
+        out = {k: tree_broadcast_leading(tree_mean_leading(s[k]), CLIENTS)
+               for k in ("params", "opt")}
+        return dict(s, **LS._client_sharded(out, mesh, "data"))
+
+    ref = jax.jit(mean_round, donate_argnums=(0,)).lower(state).compile()
+    text = compiled.as_text()
+    assert not re.search(
+        r" (all-reduce|all-gather|reduce-scatter)(-start)?\(", text)
+    assert _ops(text) == _ops(ref.as_text())
