@@ -78,8 +78,8 @@ def run_hierarchical(loss_fn, eval_fn, p0, data, n_clients: int,
 
     Returns two rows. Rounds must match (same stage stream) and modeled
     bytes must be bit-identical (both front-ends price the same
-    ``engine.Hierarchical`` topology — the driver's from its executed
-    two-level sync step's tags); asserted here so the bench doubles as the
+    ``engine.Hierarchical`` topology — the driver's the one its
+    two-level sync step carries); asserted here so the bench doubles as the
     smoke test for the hierarchical driver path.
     """
     T1 = 512 if quick else 2048

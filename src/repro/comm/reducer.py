@@ -386,8 +386,7 @@ def supports_leaf_bytes(reducer: Reducer) -> bool:
     return type(reducer).leaf_message_bytes is not Reducer.leaf_message_bytes
 
 
-def reduce_streaming(reducer: Reducer, stacked, state, rng, *,
-                     broadcast_n: int | None = None):
+def reduce_streaming(reducer: Reducer, stacked, state, rng):
     """One streaming round: reduce the stacked replica tree leaf by leaf.
 
     The single copy of the per-leaf round structure every streaming
@@ -398,15 +397,6 @@ def reduce_streaming(reducer: Reducer, stacked, state, rng, *,
     tree-level ``reducer.reduce`` folds (``fold_in(rng, leaf_index)``),
     so the result is bit-exact with the blocking round. Returns
     ``(consensus tree, new state)`` like ``Reducer.reduce``.
-
-    ``broadcast_n`` additionally emits the *per-leaf downlink*: each leaf
-    is rebroadcast to ``(broadcast_n, ...)`` replicas immediately after
-    its reduce, inside the same per-leaf loop, so under jit every leaf's
-    reduce → broadcast pair is one self-contained data-independent unit
-    XLA may overlap with the remaining leaves — the execution mirror of
-    ``runtime.StreamingSchedule.broadcast_events``. The returned tree then
-    carries the leading replica axis (numerics are bit-exact with
-    broadcasting the blocking consensus after the fact).
     """
     leaves, treedef = jax.tree.flatten(stacked)
     states = reducer.split_state(state, treedef)
@@ -415,9 +405,6 @@ def reduce_streaming(reducer: Reducer, stacked, state, rng, *,
     for i in reversed(range(len(leaves))):
         out[i], new[i] = reducer.reduce_leaf(
             leaves[i], states[i], jax.random.fold_in(rng, i))
-        if broadcast_n is not None:
-            out[i] = jnp.broadcast_to(out[i][None],
-                                      (broadcast_n,) + out[i].shape)
     return treedef.unflatten(out), reducer.join_state(new, treedef)
 
 

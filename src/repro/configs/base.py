@@ -143,17 +143,13 @@ class TrainConfig:
     topk_frac: float = 0.1       # kept fraction for reducer="topk"
     comm_latency_s: float = 5e-3      # α: fixed per-round latency
     comm_bandwidth_gbps: float = 1.0  # β⁻¹: link bandwidth
-    # communication topology (repro.engine): "star" is the paper's flat
-    # parameter-server setting; "hier" splits clients into n_pods pods —
-    # ``reducer`` runs intra-pod over calibrated ICI, ``inter_reducer``
-    # inter-pod over the comm_latency_s/comm_bandwidth_gbps WAN link.
-    # Honored by both front-ends: the vmapped simulator reduces through
-    # engine.Hierarchical, and the StagewiseDriver executes the same
-    # two-level round via a local_sgd.build_sync_step(hierarchical=True,
-    # n_pods=..., inter_reducer=...) sync step (whose tags must agree with
-    # these fields — the driver refuses mismatches so the ledger always
-    # prices the round the collectives execute). n_pods=1 degenerates to
-    # the flat star round bit-exactly (no inter-pod link exists).
+    # communication topology, resolved by engine.get_topology: "star" is
+    # the paper's flat parameter-server setting; "hier" splits clients
+    # into n_pods pods — ``reducer`` runs intra-pod over calibrated ICI,
+    # ``inter_reducer`` inter-pod over the comm_latency_s /
+    # comm_bandwidth_gbps WAN link; n_pods=1 is the flat star round. The
+    # StagewiseDriver prices the topology its sync step carries, and
+    # refuses a step that runs other pods than a "hier" config names.
     topology: str = "star"
     n_pods: int = 2
     inter_reducer: str = "int8"

@@ -1,6 +1,6 @@
 """Distributed Local-SGD step builders (pjit + client replicas on a mesh axis).
 
-The paper's clients map to a mesh axis (DESIGN.md §2/§4):
+The paper's clients map to a mesh axis (docs/topologies.md):
 
   * ``train_step_local`` — every client takes one SGD step on its own replica.
     Parameters carry a leading client axis sharded on ``client_axis``; the
@@ -8,17 +8,15 @@ The paper's clients map to a mesh axis (DESIGN.md §2/§4):
     emits **zero collectives on the client axis** (tensor-parallel collectives
     on ``model`` remain). Executed k_s times per round.
 
-  * ``sync_step`` — Algorithm 1 line 5: the parameter-averaging round. One
-    all-reduce of params (+ optimizer moments) over the client axis; with
-    one replica per device, bfloat16 params take a float32 reduce-scatter
-    and an all-gather in their own dtype instead (``_gathered_mean``).
-
-  * two-level sync (``client_axis=("pod", "data")`` + ``inter_reducer``):
-    the paper's clients live on the pod×data grid and every sync runs the
-    real hierarchical round — a dense intra-pod reduce over ``data``
-    followed by a (typically compressed) inter-pod hop over ``pod`` — via
-    ``build_sync_step(hierarchical=True)``, the same ``engine.Hierarchical``
-    reduce the simulator executes (see docs/topologies.md).
+  * ``sync_step`` — Algorithm 1 line 5: the parameter-averaging round,
+    the ``engine.Topology`` that ``engine.get_topology`` resolves (flat
+    star, per-leaf streaming star, or the two-level pod round), the same
+    reduce the simulator executes. The dense star is one all-reduce of
+    params (+ optimizer moments) over the client axis; with one replica
+    per device, bfloat16 params take a float32 reduce-scatter and an
+    all-gather in their own dtype instead (``_gathered_mean``). The
+    two-level round (``client_axis=("pod", "data")`` + ``inter_reducer``)
+    reduces intra-pod over ``data`` and inter-pod over ``pod``.
 
   * hierarchical pod-client mode (``client_axis="pod"``): grads are
     additionally all-reduced over ``data`` *inside* the local step (SyncSGD
@@ -39,16 +37,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.comm import get_reducer
-from repro.comm.reducer import DenseMean, reduce_streaming
 from repro.configs.base import ArchConfig
+from repro.core.round import end_round
+from repro.engine.topology import Star, Topology, get_topology
 from repro.kernels.flash_attention.causal import kernel_mesh
 from repro.models import transformer as TF
 from repro.obs.lowered import lowering_counter
 from repro.optim import make_optimizer
 from repro.sharding import param_specs, scatter_dim
 from repro.sharding.rules import cache_specs
-from repro.utils.tree import tree_broadcast_leading, tree_mean_leading
+from repro.utils.tree import tree_broadcast_leading
 
 
 # ---------------------------------------------------------------------------
@@ -175,105 +173,51 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
                     client_axis="data"):
     """Reducer-aware Algorithm 1 line 5: the parameter-averaging round.
 
-    Returns ``sync_step(state) -> state``. With the default DenseMean this is
-    exactly the historical dense average (and leaves the state tree
-    untouched). With a compressed reducer, each client's message is
-    compressed with error feedback; the residual state rides in
-    ``state["comm"]`` (created on first sync), and the reducer rng derives
-    from ``state["step"]`` so the step stays a pure jittable function.
-    Optimizer moments are always dense-averaged — they never cross the
-    network in a real deployment (the average mirrors Alg. 1's replica
-    consensus, not a transmitted payload).
-
-    ``streaming=True`` emits the *per-leaf* round (``engine.StreamingStar``
-    semantics): one independent ``reduce_leaf`` per parameter leaf, in
-    reverse-layer order — the order leaves finish their last local step
-    under backprop. Numerics are bit-exact with the blocking round (each
-    leaf folds the same per-leaf rng), but the reduce is expressed as
-    per-leaf data-independent ops, so when the step runs under jit XLA's
-    scheduler is free to interleave leaf l's reduce with the remaining
-    leaves' compute instead of waiting on one whole-tree collective. The
-    consensus broadcast is emitted per leaf inside the same loop (the
-    downlink mirror of the per-leaf uplink). Composes with
-    ``hierarchical=True``: the two-level round then runs per leaf too
-    (``Hierarchical(streaming=True)`` — intra-pod reduce feeding the
-    inter-pod reduce leaf by leaf).
-
-    ``hierarchical=True`` emits the *two-level* round
-    (``engine.Hierarchical`` semantics, see ``docs/topologies.md``): an
-    intra-pod reduce with ``reducer`` (dense by default — the hop rides
-    cheap ICI) followed by an inter-pod reduce of the ``n_pods`` pod means
-    with ``inter_reducer`` (int8-EF by default — the hop crosses the WAN).
-    Clients are pods' contiguous slices of the leading client axis, the
-    layout a ``(pod, data, model)`` mesh shards pod-major, so under pjit
-    the intra hop's collectives stay on the ``data`` axis and the inter
-    hop's on the ``pod`` axis — the driver's collectives structurally
-    match what the ``Hierarchical`` cost model prices. The round *is*
-    ``Hierarchical.reduce`` (one shared code path), so it is bit-exact
-    with the simulator's hierarchical trace on the same rng; per-hop
-    error-feedback residuals ride in ``state["comm"]``. Degenerate cases
-    keep the flat contract exactly: ``n_pods=1`` (no inter-pod link
-    exists) and dense∘dense (the two-level mean collapses to the flat
-    mean) both produce the flat round bit-exactly.
+    Returns ``sync_step(state) -> state``, which runs one
+    ``engine.Topology`` and carries it as ``sync_step.topology``:
+    ``get_topology`` resolves it from ``reducer`` (the uplink reducer,
+    or the intra-pod one), ``streaming`` (the per-leaf round XLA can
+    overlap with compute), ``hierarchical`` (``n_pods`` pods of
+    contiguous clients and an ``inter_reducer`` hop between them; one
+    pod is the flat star); a ``Topology`` passed as ``reducer`` is run
+    as it is. The round reduces the params with the topology, folding
+    ``state["step"]`` into ``base_seed`` for its rng so the step stays
+    pure, then broadcasts the consensus and the averaged optimizer
+    moments (``core.round.end_round``); the moments never cross the
+    network in a real deployment. A stateful round (compressed reducers'
+    error feedback) keeps its state in ``state["comm"]``, created on the
+    first round; a stateless one leaves the state tree's keys as they are.
 
     ``mesh`` (with the ``client_axis`` the replicas are sharded over)
     keeps the round's output params and moments split over the client
-    axis, as its input was. Where that axis holds one replica per device
-    and no other axis splits a leaf, the dense blocking round averages
-    its narrow leaves through ``_gathered_mean``; each round lowered counts
-    once under ``sync.lowered{path=scatter_gather|all_reduce}``.
+    axis, as its input was. Where a dense blocking star runs with one
+    replica per device and no other axis splits a leaf, its narrow
+    leaves are averaged through ``_gathered_mean``; each round lowered
+    counts once under ``sync.lowered{path=scatter_gather|all_reduce}``.
     """
-    reducer = get_reducer(reducer)
-    dense = isinstance(reducer, DenseMean)
-
-    if hierarchical:
-        if n_pods < 1:
-            raise ValueError(f"n_pods must be >= 1, got {n_pods}")
-        if n_pods > 1:
-            return _build_two_level_sync_step(reducer, n_pods, inter_reducer,
-                                              base_seed, streaming, mesh,
-                                              client_axis)
-        # n_pods == 1: a single pod has no inter-pod hop to cross — the
-        # round degenerates to the flat round with the intra reducer
-        # (streaming or blocking; bit-exact with the flat path by
-        # construction; the inter reducer is unused because no WAN link
-        # exists)
+    topology = get_topology(
+        reducer if isinstance(reducer, Topology)
+        else ("streaming-" if streaming else "")
+        + ("hier" if hierarchical else "star"),
+        reducer=reducer, n_pods=n_pods, inter_reducer=inter_reducer)
+    dense_star = type(topology) is Star and not topology.stateful
 
     def sync_step(state):
         # named scopes "reduce" and "broadcast" name the round's two halves
         # in a profile's op metadata
         n = jax.tree.leaves(state["params"])[0].shape[0]
-        gather = (dense and not streaming
-                  and _one_replica_per_device(mesh, client_axis, n))
+        gather = dense_star and _one_replica_per_device(mesh, client_axis, n)
         rng = jax.random.fold_in(jax.random.key(base_seed), state["step"])
-        comm = None
+        out = dict(state)
         with jax.named_scope("reduce"):
-            opt = tree_mean_leading(state["opt"])
-            if dense and not streaming:
-                params = tree_mean_leading(state["params"])
-            elif dense:
-                # streaming dense round: per-leaf mean + per-leaf
-                # rebroadcast inside the same reversed loop (state tree
-                # untouched, like the blocking dense round; rng unused) —
-                # leaf l's reduce and downlink broadcast form one
-                # data-independent unit under jit
-                params, _ = reduce_streaming(reducer, state["params"], None,
-                                             rng, broadcast_n=n)
-            else:
-                comm = state.get("comm")
-                if comm is None:
-                    comm = reducer.init_state(state["params"])
-                if streaming:
-                    params, comm = reduce_streaming(
-                        reducer, state["params"], comm, rng, broadcast_n=n)
-                else:
-                    params, comm = reducer.reduce(state["params"], comm, rng)
+            comm = state.get("comm") if topology.stateful else None
+            if topology.stateful and comm is None:
+                comm = topology.init_state(state["params"])
+            consensus, comm = topology.reduce(state["params"], comm, rng)
+        if topology.stateful:
+            out["comm"] = comm
+        out["params"], out["opt"] = end_round(consensus, state["opt"], n)
         with jax.named_scope("broadcast"):
-            out = dict(state, opt=tree_broadcast_leading(opt, n),
-                       params=(params if streaming
-                               else tree_broadcast_leading(params, n)))
-            if comm is not None:
-                out["comm"] = comm
             out.update(_client_sharded({"params": out["params"],
                                         "opt": out["opt"]}, mesh,
                                        client_axis))
@@ -284,106 +228,25 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
             state["step"], path="scatter_gather" if gather else "all_reduce")
         return out
 
-    # tag the step with its reducer (and round structure) so
-    # StagewiseDriver's comm accounting can't drift from what the round
-    # actually transmits
-    sync_step.reducer = reducer
-    sync_step.streaming = streaming
-    sync_step.hierarchical = False
+    # the driver prices the topology the round runs
+    sync_step.topology = topology
     return sync_step
 
 
-def _build_two_level_sync_step(intra, n_pods: int, inter_reducer,
-                               base_seed: int, streaming: bool = False,
-                               mesh=None, client_axis="data"):
-    """The hierarchical (n_pods > 1) round behind ``build_sync_step``.
-
-    One ``engine.Hierarchical.reduce`` per sync — the same code path the
-    vmapped simulator executes for ``topology="hier"`` — with the per-hop
-    reducer state riding in ``state["comm"]`` (created on first sync, like
-    the flat compressed round). The dense∘dense configuration keeps the
-    state tree untouched: ``Hierarchical`` collapses it to the flat mean
-    and its reducer state is inert, so the round matches the flat dense
-    round exactly, key set included.
-
-    ``streaming=True`` executes the same round per leaf
-    (``Hierarchical(streaming=True)``): leaf l's intra-pod reduce feeds
-    its inter-pod reduce immediately, in reverse-layer order, so under
-    jit the WAN collective of late leaves is free to overlap the
-    intra-pod reduction of the early ones. Bit-exact with the blocking
-    two-level round (same per-leaf rng folds on both hops).
-    """
-    from repro.engine.topology import Hierarchical
-
-    inter = get_reducer(inter_reducer)
-    topo = Hierarchical(n_pods=n_pods, intra=intra, inter=inter,
-                        streaming=streaming)
-
-    def sync_step(state):
-        n = jax.tree.leaves(state["params"])[0].shape[0]
-        if n % n_pods:
-            # concrete at trace time — same contract as Hierarchical
-            raise ValueError(
-                f"{n} client replicas not divisible into {n_pods} pods")
-        rng = jax.random.fold_in(jax.random.key(base_seed), state["step"])
-        comm = None
-        with jax.named_scope("reduce"):
-            opt = tree_mean_leading(state["opt"])
-            if topo.all_dense:
-                consensus, _ = topo.reduce(state["params"], None, rng)
-            else:
-                comm = state.get("comm")
-                if comm is None:
-                    comm = topo.init_state(state["params"])
-                consensus, comm = topo.reduce(state["params"], comm, rng)
-        with jax.named_scope("broadcast"):
-            out = dict(state, params=tree_broadcast_leading(consensus, n),
-                       opt=tree_broadcast_leading(opt, n))
-            if comm is not None:
-                out["comm"] = comm
-            out.update(_client_sharded({"params": out["params"],
-                                        "opt": out["opt"]}, mesh,
-                                       client_axis))
-        return out
-
-    # tags: the driver prices the topology the round actually executes
-    sync_step.reducer = intra
-    sync_step.streaming = streaming
-    sync_step.hierarchical = True
-    sync_step.n_pods = n_pods
-    sync_step.inter_reducer = inter
-    return sync_step
-
-
-def sync_step_tags(sync_step) -> dict:
-    """The comm tags ``build_sync_step`` stamped on a round, read through
+def sync_step_topology(sync_step) -> Optional[Topology]:
+    """The ``Topology`` a ``build_sync_step`` round carries, read through
     any stack of wrappers that chain ``__wrapped__`` (``jax.jit``,
-    ``functools.wraps`` decorators like ``obs.ProfileSession.wrap``).
-
-    Returns ``{"reducer", "streaming", "hierarchical"}`` plus
-    ``{"n_pods", "inter_reducer"}`` for two-level rounds; absent tags come
-    back ``None``/``False``. ``StagewiseDriver`` reads its comm accounting
-    *and* its trace-span attributes from here, so the priced ledger and
-    the exported timeline can't drift from the round the step executes.
-    """
-    def tag(name, default=None):
-        fn, v = sync_step, None
-        for _ in range(8):   # walk the full wrapper chain (cycle-safe)
-            if fn is None:
-                break
-            v = getattr(fn, name, None)
-            if v is not None:
-                break
-            fn = getattr(fn, "__wrapped__", None)
-        return default if v is None else v
-
-    tags = {"reducer": tag("reducer"),
-            "streaming": bool(tag("streaming", False)),
-            "hierarchical": bool(tag("hierarchical", False))}
-    if tags["hierarchical"]:
-        tags["n_pods"] = tag("n_pods")
-        tags["inter_reducer"] = tag("inter_reducer")
-    return tags
+    ``functools.wraps`` decorators like ``obs.ProfileSession.wrap``);
+    ``None`` for a step that carries none."""
+    fn = sync_step
+    for _ in range(8):   # walk the full wrapper chain (cycle-safe)
+        if fn is None:
+            return None
+        topology = getattr(fn, "topology", None)
+        if topology is not None:
+            return topology
+        fn = getattr(fn, "__wrapped__", None)
+    return None
 
 
 def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
@@ -401,8 +264,9 @@ def build_train_steps(cfg: ArchConfig, mesh, *, client_axis: str = "data",
         state = {"params": (C, ...), "opt": (C, ...), "step": scalar}
     sync_step(state) -> state   (client-axis parameter average; built by
         ``build_sync_step(reducer, streaming=streaming)`` — pass ``reducer``
-        for a compressed round, default dense; ``streaming=True`` for the
-        per-leaf reduce XLA can overlap with compute)
+        for a compressed round (or an ``engine.Topology`` to run as is),
+        default dense; ``streaming=True`` for the per-leaf reduce XLA can
+        overlap with compute)
 
     ``microbatch`` > 1 splits each client's batch into that many
     gradient-accumulation slices (scan), dividing activation memory.

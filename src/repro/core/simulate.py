@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from repro.comm import get_reducer
 from repro.configs.base import TrainConfig
 from repro.core.prox import prox_loss
+from repro.core.round import end_round
 from repro.engine.engine import Engine, StageStatus
 from repro.obs.trace import CAT_COMM, CAT_COMPUTE
 from repro.utils.tree import tree_broadcast_leading, tree_mean_leading, tree_zeros_like
@@ -149,8 +150,7 @@ def make_round_fn(loss_fn, *, k: int, batch: int, momentum: float,
             local_step, (params, mom, t), jax.random.split(rng_r, k))
         consensus, comm = reducer.reduce(
             params, comm, jax.random.fold_in(rng_r, _COMM_SALT))
-        params = tree_broadcast_leading(consensus, N)
-        mom = tree_broadcast_leading(tree_mean_leading(mom), N)
+        params, mom = end_round(consensus, mom, N)
         return (params, mom, t, comm)
 
     if masked:
@@ -369,9 +369,8 @@ class VmapSimulatorBackend:
             def sync_fn(params, mom, comm, rng):
                 N = jax.tree.leaves(params)[0].shape[0]
                 consensus, comm = topo.reduce(params, comm, rng)
-                return (tree_broadcast_leading(consensus, N),
-                        tree_broadcast_leading(tree_mean_leading(mom), N),
-                        comm, consensus)
+                params, mom = end_round(consensus, mom, N)
+                return params, mom, comm, consensus
 
             self._chunk_cache[key] = (step_fn, sync_fn)
         return self._chunk_cache[key]

@@ -21,12 +21,11 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.comm import NetworkModel, get_reducer, link_model
 from repro.configs.base import TrainConfig
-from repro.core.local_sgd import sync_step_tags
+from repro.core.local_sgd import sync_step_topology
 from repro.engine.algorithm import get_algorithm
-from repro.engine.engine import Engine, StageStatus
-from repro.engine.topology import Hierarchical, Star, StreamingStar
+from repro.engine.engine import Engine, StageStatus, config_link, topology_for
+from repro.engine.topology import Hierarchical, get_topology
 from repro.obs.trace import CAT_COMM, CAT_COMPUTE
 from repro.utils.tree import tree_broadcast_leading, tree_mean_leading
 from repro.utils.logging import get_logger
@@ -194,109 +193,55 @@ class DriverBackend:
         return self.ds
 
 
+def _pods(topology):
+    """``(n_pods, inter-pod reducer name)`` of a two-level round, else
+    ``None``."""
+    if isinstance(topology, Hierarchical):
+        return topology.n_pods, topology.inter.name
+    return None
+
+
 class StagewiseDriver:
     """Runs cfg.algo over a stream of batches.
 
     train_step(state, batch, eta[, center]) -> (state, metrics)
     sync_step(state) -> state
 
-    The sync round's *shape* follows the sync_step's tags (set by
-    ``local_sgd.build_sync_step``; explicit args and ``tcfg.topology``
-    must agree with them): flat star (default), per-leaf streaming star
-    (``streaming=True``), or the two-level hierarchical round
-    (``hierarchical=True`` — dense intra-pod over ``data``, compressed
-    inter-pod over ``pod``; ``tcfg.n_pods`` / ``tcfg.inter_reducer``).
-    The engine then prices exactly that topology, so
-    ``DriverState.comm_bytes_total`` and the per-(leaf, hop)
-    ``leaf_ledger`` always describe the collectives the run emitted.
+    The priced round is the ``engine.Topology`` the sync_step carries
+    (``local_sgd.build_sync_step`` puts it there) on the config's α–β
+    link, or the config's own (``engine.topology_for``) for a step that
+    carries none. A config that names a pod topology pins its pods: the
+    step must reduce over ``n_pods`` pods with the same inter-pod reducer
+    (one pod: the flat star). ``DriverState.comm_bytes_total`` and the
+    per-(leaf, hop) ``leaf_ledger`` so describe the collectives the run
+    emitted.
     """
 
     def __init__(self, tcfg: TrainConfig, train_step: Callable,
-                 sync_step: Callable, uses_center: bool = False,
-                 reducer=None):
+                 sync_step: Callable, uses_center: bool = False):
         self.tcfg = tcfg
         self.train_step = train_step
         self.sync_step = sync_step
         self.uses_center = uses_center
-        # Comm accounting reducer, in priority order: explicit arg > the
-        # reducer the sync_step itself was built with (local_sgd.
-        # build_sync_step tags it, surviving jax.jit via __wrapped__) >
-        # tcfg.reducer. The tag keeps accounting from silently diverging
-        # from what the round actually transmits — the driver prices
-        # exactly the topology the sync_step executes (flat star,
-        # per-leaf streaming star, or the two-level hierarchical round).
-        tags = sync_step_tags(sync_step)
-
-        def tag(name, default=None):
-            v = tags.get(name)
-            return default if v is None else v
-
-        if reducer is None:
-            reducer = tag("reducer")
-        self.reducer = get_reducer(
-            reducer if reducer is not None else tcfg.reducer,
-            quant_bits=tcfg.quant_bits, topk_frac=tcfg.topk_frac)
-        topo_spec = getattr(tcfg, "topology", "star")
-        stream_hier_specs = ("streaming-hier", "hier-streaming",
-                             "streaming-hierarchical")
-        hier_spec = (topo_spec in ("hier", "hierarchical", "pods")
-                     or topo_spec in stream_hier_specs)
-        # a sync_step built with build_sync_step(streaming=True) implies the
-        # per-leaf round even when the config says plain "star"
-        self.streaming = (topo_spec in ("streaming", "streaming-star",
-                                        "stream")
-                          or topo_spec in stream_hier_specs
-                          or bool(tag("streaming", False)))
-        # ... and a hierarchical-tagged sync_step implies the two-level
-        # round the same way. cfg n_pods=1 is the flat degenerate case
-        # (no inter-pod link exists; build_sync_step emits the flat round).
-        # streaming and hierarchical compose: the per-leaf two-level round
-        # (Hierarchical(streaming=True)) prices like the blocking one.
-        self.hierarchical = bool(tag("hierarchical", False)) or (
-            hier_spec and getattr(tcfg, "n_pods", 2) > 1)
-        if self.hierarchical:
-            if not tag("hierarchical", False):
-                # cfg promises a two-level round but the step transmits a
-                # flat average: pricing Hierarchical would ledger bytes
-                # the collectives never move.
-                raise ValueError(
-                    f"topology={tcfg.topology!r} needs a two-level sync "
-                    f"step: build it with local_sgd.build_sync_step("
-                    f"reducer, hierarchical=True, n_pods={tcfg.n_pods}, "
-                    f"inter_reducer={tcfg.inter_reducer!r})")
-            n_pods = tag("n_pods")
-            if hier_spec and n_pods != tcfg.n_pods:
-                raise ValueError(
-                    f"sync_step reduces over {n_pods} pods but the config "
-                    f"says n_pods={tcfg.n_pods}; the ledger would price a "
-                    f"different topology than the round executes")
-            self.n_pods = n_pods
-            self.inter_reducer = get_reducer(
-                tag("inter_reducer", getattr(tcfg, "inter_reducer", "int8")),
-                quant_bits=tcfg.quant_bits, topk_frac=tcfg.topk_frac)
-            cfg_inter = get_reducer(getattr(tcfg, "inter_reducer", "int8"),
-                                    quant_bits=tcfg.quant_bits,
-                                    topk_frac=tcfg.topk_frac)
-            if hier_spec and tag("inter_reducer") is not None \
-                    and self.inter_reducer.name != cfg_inter.name:
-                # same contract as the n_pods check: cfg-derived reports
-                # (comm_summary_for) and the executed ledger must price
-                # the same WAN hop
-                raise ValueError(
-                    f"sync_step compresses the inter-pod hop with "
-                    f"{self.inter_reducer.name!r} but the config says "
-                    f"inter_reducer={tcfg.inter_reducer!r}; the ledger "
-                    f"would price a different round than the one executed")
-        elif topo_spec not in (None, "star", "flat", "streaming",
-                               "streaming-star", "stream") and not hier_spec:
+        configured = topology_for(tcfg)
+        carried = sync_step_topology(sync_step)
+        # a config that names pods (its spec is two-level at two pods)
+        # pins them, one pod (the flat star) included: otherwise the
+        # ledger would price an inter-pod hop the round never crosses, or
+        # other pods than the round reduces over
+        if isinstance(get_topology(tcfg.topology, n_pods=2), Hierarchical) \
+                and _pods(carried) != _pods(configured):
+            runs = ("no two-level round" if _pods(carried) is None else
+                    "%d pods with inter_reducer=%r" % _pods(carried))
             raise ValueError(
-                f"unknown topology spec for StagewiseDriver: "
-                f"{tcfg.topology!r} (expected star/streaming/hierarchical/"
-                f"streaming-hier)")
-        self.net = NetworkModel(
-            latency_s=tcfg.comm_latency_s,
-            bandwidth_gbps=tcfg.comm_bandwidth_gbps,
-            count_downlink=getattr(tcfg, "count_downlink", False))
+                f"topology={tcfg.topology!r} needs the two-level round "
+                f"local_sgd.build_sync_step(reducer, hierarchical=True, "
+                f"n_pods={tcfg.n_pods}, inter_reducer="
+                f"{tcfg.inter_reducer!r}) builds, but the sync step runs "
+                f"{runs}: the ledger would price a different round than "
+                f"the one executed")
+        self.topology = (configured if carried is None
+                         else carried.on_link(config_link(tcfg)))
         self.algorithm = get_algorithm(tcfg.algo)
         policy = self.algorithm.sync_policy
         if getattr(policy, "asynchronous", False):
@@ -318,30 +263,15 @@ class StagewiseDriver:
                 f"simulator (core.simulate.run) or the event runtime "
                 f"(repro.runtime.EventBackend)")
         self.stages = self.algorithm.stages(tcfg)
-        # trace-span attributes of one sync round — derived from the same
-        # tags the ledger prices, so trace and ledger agree by construction
-        self.span_attrs = {"reducer": self.reducer.name,
-                           "streaming": self.streaming,
-                           "hierarchical": self.hierarchical}
-        if self.hierarchical:
-            self.span_attrs.update(n_pods=self.n_pods,
-                                   inter_reducer=self.inter_reducer.name)
-
-    def build_topology(self):
-        """The priced Topology of one sync round — exactly the round the
-        tagged sync_step executes. Streaming rounds price identically to
-        Star (same bytes, same serial α–β time) but additionally carry
-        the per-leaf ledger; hierarchical rounds price per hop
-        (calibrated ICI intra-pod, the config's α–β link inter-pod).
-        Also what ``--profile`` uses to price one sync step."""
-        if self.hierarchical:
-            return Hierarchical(n_pods=self.n_pods, intra=self.reducer,
-                                inter=self.inter_reducer,
-                                intra_net=link_model("ici"),
-                                inter_net=self.net,
-                                streaming=self.streaming)
-        topo_cls = StreamingStar if self.streaming else Star
-        return topo_cls(reducer=self.reducer, network=self.net)
+        # trace-span attributes of one sync round — derived from the
+        # topology the ledger prices, so trace and ledger agree by
+        # construction
+        t, pods = self.topology, _pods(self.topology)
+        self.span_attrs = {"reducer": (t.intra if pods else t.reducer).name,
+                           "streaming": t.streaming,
+                           "hierarchical": pods is not None}
+        if pods:
+            self.span_attrs.update(n_pods=pods[0], inter_reducer=pods[1])
 
     def run(self, state: dict, batches, max_iters: Optional[int] = None,
             tracer=None, series=None) -> DriverState:
@@ -349,11 +279,10 @@ class StagewiseDriver:
         # a fresh Engine per run: its report is the run's comm ledger,
         # priced on exactly the topology the sync_step executes —
         # modeled and executed bytes cannot diverge.
-        engine = Engine(self.algorithm, self.tcfg,
-                        topology=self.build_topology(),
+        engine = Engine(self.algorithm, self.tcfg, topology=self.topology,
                         tracer=tracer, series=series)
         ds = engine.run(DriverBackend(self, ds, batches, max_iters))
-        log.info("comm_summary", reducer=self.reducer.name,
+        log.info("comm_summary", reducer=self.span_attrs["reducer"],
                  rounds=ds.rounds_total, comm_bytes=ds.comm_bytes_total,
                  comm_time_s=ds.comm_time_s)
         return ds

@@ -80,6 +80,14 @@ class EngineReport:
     series: dict = field(default_factory=dict)
 
 
+def config_link(cfg) -> NetworkModel:
+    """The α–β link a TrainConfig describes (``comm_latency_s``,
+    ``comm_bandwidth_gbps``, ``count_downlink``)."""
+    return NetworkModel(latency_s=cfg.comm_latency_s,
+                        bandwidth_gbps=cfg.comm_bandwidth_gbps,
+                        count_downlink=getattr(cfg, "count_downlink", False))
+
+
 def topology_for(cfg, reducer=None, topology=None) -> Topology:
     """Resolve a Topology from a TrainConfig's comm fields.
 
@@ -90,13 +98,10 @@ def topology_for(cfg, reducer=None, topology=None) -> Topology:
     """
     if isinstance(topology, Topology):
         return topology
-    net = NetworkModel(latency_s=cfg.comm_latency_s,
-                       bandwidth_gbps=cfg.comm_bandwidth_gbps,
-                       count_downlink=getattr(cfg, "count_downlink", False))
     return get_topology(
         topology if topology is not None else getattr(cfg, "topology", "star"),
         reducer=reducer if reducer is not None else cfg.reducer,
-        network=net, n_pods=getattr(cfg, "n_pods", 2),
+        network=config_link(cfg), n_pods=getattr(cfg, "n_pods", 2),
         inter_reducer=getattr(cfg, "inter_reducer", "int8"),
         quant_bits=cfg.quant_bits, topk_frac=cfg.topk_frac)
 

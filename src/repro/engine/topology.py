@@ -20,7 +20,7 @@ function is agnostic to whether it averages over one hop or two — and
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import List, Optional
 
 import jax
@@ -104,6 +104,17 @@ class Topology:
         tree without the client axis, new state). jit/scan-safe."""
         raise NotImplementedError
 
+    @property
+    def stateful(self) -> bool:
+        """Whether ``reduce`` reads and returns reducer state (error-feedback
+        residuals); a stateless round ignores the state it is given."""
+        raise NotImplementedError
+
+    def on_link(self, network: NetworkModel) -> "Topology":
+        """This round priced over ``network``: the hop a config's α–β link
+        describes (the uplink of a star, the inter-pod hop of pods)."""
+        raise NotImplementedError
+
     def hop_costs(self, template, n_clients: int) -> List[HopCost]:
         """Price one round hop by hop: total payload bytes crossing each
         hop and its serial α–β time in modeled seconds (``template`` is a
@@ -163,12 +174,20 @@ class Star(Topology):
     network: NetworkModel = field(default_factory=NetworkModel)
 
     name = "star"
+    streaming = False
 
     def init_state(self, stacked):
         return self.reducer.init_state(stacked)
 
     def reduce(self, stacked, state, rng):
         return self.reducer.reduce(stacked, state, rng)
+
+    @property
+    def stateful(self) -> bool:
+        return not isinstance(self.reducer, DenseMean)
+
+    def on_link(self, network):
+        return replace(self, network=network)
 
     def hop_costs(self, template, n_clients: int) -> List[HopCost]:
         up = n_clients * self.reducer.message_bytes(template)
@@ -213,7 +232,7 @@ class StreamingStar(Star):
     reverse-layer order — the order leaves finish their last local step
     under backprop. That is the structure a jit'd sync step needs for XLA
     to interleave leaf l's reduce with the remaining leaves' compute, and
-    it is what ``local_sgd.build_sync_step(streaming=True)`` emits; the
+    it is what ``local_sgd.build_sync_step(streaming=True)`` runs; the
     cost model (``hop_costs`` / ``leaf_costs``) is inherited unchanged, so
     streaming and blocking ledgers reconcile by construction. The modeled
     *overlap* win is priced by ``runtime.StreamingSchedule``, not here —
@@ -221,6 +240,7 @@ class StreamingStar(Star):
     """
 
     name = "streaming-star"
+    streaming = True
 
     def reduce(self, stacked, state, rng):
         """The per-leaf round: ``comm.reduce_streaming`` over the uplink
@@ -242,7 +262,7 @@ class Hierarchical(Topology):
     the per-pod slice) lowers to collectives on the ``data`` mesh axis
     only, and the inter hop (``inter.reduce`` over the ``n_pods`` stacked
     pod means) to collectives on the ``pod`` axis only.
-    ``local_sgd.build_sync_step(hierarchical=True)`` executes exactly this
+    ``local_sgd.build_sync_step(hierarchical=True)`` runs exactly this
     method, so the driver's collectives and the simulator's hierarchical
     trace are the same code path (bit-exact on the same rng).
 
@@ -284,10 +304,13 @@ class Hierarchical(Topology):
         return "streaming-hier" if self.streaming else "hierarchical"
 
     @property
-    def all_dense(self) -> bool:
-        """True when both hops are DenseMean — the collapsible case."""
-        return (type(self.intra) is DenseMean
-                and type(self.inter) is DenseMean)
+    def stateful(self) -> bool:
+        """False when both hops are DenseMean — the collapsible case."""
+        return not (type(self.intra) is DenseMean
+                    and type(self.inter) is DenseMean)
+
+    def on_link(self, network):
+        return replace(self, inter_net=network)
 
     def _pods(self, stacked):
         P = self.n_pods
@@ -308,19 +331,23 @@ class Hierarchical(Topology):
                 x.reshape((P, x.shape[0] // P) + x.shape[1:]), axis=1),
             stacked)
 
-    def init_state(self, stacked):
+    def _check_pods(self, stacked):
         n = jax.tree.leaves(stacked)[0].shape[0]
         if n % self.n_pods:
             raise ValueError(
                 f"{n} clients not divisible into {self.n_pods} pods")
+
+    def init_state(self, stacked):
+        self._check_pods(stacked)
         pods = self._pods(stacked)
         return {"intra": tuple(self.intra.init_state(p) for p in pods),
                 "inter": self.inter.init_state(self._pod_means(stacked))}
 
     def reduce(self, stacked, state, rng):
+        self._check_pods(stacked)
         if self.streaming:
             return self._reduce_streaming(stacked, state, rng)
-        if self.all_dense:
+        if not self.stateful:
             # see class docstring: dense∘dense ≡ the flat mean, computed
             # as such so the two-level round is bit-exact with Star
             return jax.tree.map(lambda x: jnp.mean(x, axis=0),
@@ -361,7 +388,7 @@ class Hierarchical(Topology):
         """
         leaves, treedef = jax.tree.flatten(stacked)
         P = self.n_pods
-        if self.all_dense:
+        if not self.stateful:
             out = [None] * len(leaves)
             for i in reversed(range(len(leaves))):
                 out[i] = jnp.mean(leaves[i], axis=0)
@@ -469,8 +496,9 @@ def get_topology(spec, *, reducer=None, network: Optional[NetworkModel] = None,
 
     Single-pod degeneracy: a hierarchical spec with ``n_pods=1`` has no
     inter-pod link, so it resolves to the flat round over ``reducer`` —
-    ``Star`` (blocking) or ``StreamingStar`` (streaming) — matching the
-    driver/``build_sync_step`` contract that one pod *is* the flat star.
+    ``Star`` (blocking) or ``StreamingStar`` (streaming): one pod *is* the
+    flat star. The pjit round (``local_sgd.build_sync_step``) resolves
+    its topology here too, so both backends run what this returns.
     """
     if isinstance(spec, Topology):
         return spec
@@ -484,6 +512,8 @@ def get_topology(spec, *, reducer=None, network: Optional[NetworkModel] = None,
                          "streaming-hierarchical")
     if spec in hier_specs + stream_hier_specs:
         streaming = spec in stream_hier_specs
+        if n_pods < 1:
+            raise ValueError(f"n_pods must be >= 1, got {n_pods}")
         if n_pods == 1:
             cls = StreamingStar if streaming else Star
             return cls(reducer=red, network=network or NetworkModel())

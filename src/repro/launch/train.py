@@ -31,7 +31,7 @@ from repro.configs.base import ShapeConfig, TrainConfig
 from repro.core import local_sgd as LS
 from repro.core.stl_sgd import StagewiseDriver
 from repro.data.synthetic import make_token_stream
-from repro.engine import algorithm_names
+from repro.engine import algorithm_names, topology_for
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_client_mesh
 from repro.utils.logging import get_logger
@@ -137,17 +137,11 @@ def main(argv=None):
                                   args.optimizer, client_axis)
     batch_sh = {k: NamedSharding(mesh, spec) for k, spec in
                 LS.batch_spec(cfg, client_axis, False).items()}
+    # the round is the config's topology (the two-level round keeps its
+    # --pods even where they share a chip), built once and run as is
     train_local, sync_step, _ = LS.build_train_steps(
         cfg, mesh, client_axis=client_axis, optimizer=args.optimizer,
-        momentum=args.momentum, reducer=args.reducer,
-        streaming=args.topology == "streaming")
-    if hier:
-        # the two-level round: dense intra-pod (args.reducer) + compressed
-        # inter-pod — the driver prices it through engine.Hierarchical
-        sync_step = LS.build_sync_step(args.reducer, hierarchical=True,
-                                       n_pods=args.pods,
-                                       inter_reducer=args.inter_reducer,
-                                       mesh=mesh, client_axis=client_axis)
+        momentum=args.momentum, reducer=topology_for(tcfg))
 
     uses_center = args.algo in ("stl_nc1", "stl_nc2") and args.gamma_inv > 0
     if uses_center:
@@ -185,8 +179,8 @@ def main(argv=None):
         # only exists below — resolve the price lazily per call
         sync_price = {"v": 0.0}
         train_fn = profile.wrap(train_fn, "train_step", train_price)
-        # wrapping keeps the build_sync_step tags reachable through the
-        # __wrapped__ chain, so the driver still prices the tagged round
+        # wrapping keeps the step's topology reachable through the
+        # __wrapped__ chain, so the driver still prices the round it runs
         sync_fn = profile.wrap(sync_fn, "sync_step",
                                lambda *a, **k: sync_price["v"])
 
@@ -196,7 +190,7 @@ def main(argv=None):
             lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
             state["params"])
         sync_price["v"] = sum(
-            h.time_s for h in driver.build_topology().hop_costs(template, C))
+            h.time_s for h in driver.topology.hop_costs(template, C))
     batches = (jax.device_put(b, batch_sh) for b in synthetic_batches(
         cfg, C, args.batch, args.seq, args.seed, args.non_iid))
     tracer = None

@@ -113,8 +113,8 @@ class ProfileSession:
 
         ``modeled_s`` is a constant price or a ``(*args, **kwargs) ->
         seconds`` callable evaluated per call. ``functools.wraps``
-        preserves ``__wrapped__``, so tag-reading consumers
-        (``local_sgd.sync_step_tags``) still see through the wrapper.
+        preserves ``__wrapped__``, so ``local_sgd.sync_step_topology``
+        still finds the round's topology through the wrapper.
         """
 
         @functools.wraps(fn)

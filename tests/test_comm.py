@@ -391,7 +391,7 @@ def test_train_sync_loop_threads_comm_state():
 
     train_step, sync_step, _ = LS.build_train_steps(
         cfg, None, loss_fn=toy_loss, reducer="int8")
-    assert sync_step.reducer.name == "int8"
+    assert sync_step.topology.reducer.name == "int8"
     key = jax.random.key(0)
     state = {"params": tree_broadcast_leading(
                  {"w": jax.random.normal(key, (d,))}, C),
@@ -410,12 +410,12 @@ def test_train_sync_loop_threads_comm_state():
     # the reference tracks the broadcast consensus exactly
     np.testing.assert_array_equal(np.asarray(state["comm"]["ref"]["w"]),
                                   np.asarray(state["params"]["w"][0]))
-    # and the driver picks the accounting reducer off the tagged sync_step
+    # and the driver prices the reducer the sync_step's topology carries
     from repro.core.stl_sgd import StagewiseDriver
 
     drv = StagewiseDriver(TrainConfig(algo="local", T1=4, k1=2.0, n_stages=1),
                           train_step, jax.jit(sync_step))
-    assert drv.reducer.name == "int8"
+    assert drv.topology.reducer.name == "int8"
 
 
 def test_cost_model_prices_compression():

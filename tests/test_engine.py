@@ -3,9 +3,8 @@
 The decisive invariants:
   * every historical algo name resolves through the registry to an
     Algorithm whose SyncPolicy reproduces the old make_stages schedule;
-  * stl_sc + DenseMean under the new Engine reproduces the pre-refactor
-    ``simulate.run`` objective trace bit-exactly (golden values captured
-    from the pre-engine revision of core/simulate.py);
+  * stl_sc + DenseMean under the Engine reproduces the recorded
+    ``simulate.run`` objective traces bit-exactly (``tests/goldens.py``);
   * the previously untested algorithms (stl_nc2, crpsgd) run end-to-end
     through both backends (vmapped simulator and StagewiseDriver);
   * the Hierarchical topology composes a dense intra-pod reduce with a
@@ -17,12 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import goldens as G
 from repro.comm import DenseMean, NetworkModel, QuantizedMean, comm_summary_for
 from repro.configs.base import TrainConfig
 from repro.core import schedules as S
 from repro.core import simulate
 from repro.core.stl_sgd import StagewiseDriver
-from repro.data import make_binary_classification, partition_iid
 from repro.engine import (
     Engine,
     EveryStep,
@@ -39,7 +38,6 @@ from repro.engine import (
     get_topology,
     topology_for,
 )
-from repro.models import logreg
 from repro.utils.tree import tree_broadcast_leading, tree_mean_leading
 
 ALL_ALGOS = ("sync", "lb", "crpsgd", "local", "stl_sc", "stl_nc1", "stl_nc2")
@@ -104,71 +102,26 @@ def test_local_update_batch_rules():
 
 
 # ---------------------------------------------------------------------------
-# Bit-exact regression: Engine vs the pre-refactor simulate.run trace
+# Bit-exact regression: Engine vs the recorded simulate.run traces
 # ---------------------------------------------------------------------------
-
-# (round, iteration, objective) trace of the pre-engine core/simulate.py
-# (commit f5d4d18) on the problem below — stl_sc + DenseMean, seed 0.
-_GOLDEN_STL_SC = [
-    (0, 0, 0.6931471824645996), (1, 2, 0.6789301633834839),
-    (2, 4, 0.6675747632980347), (3, 6, 0.6584702134132385),
-    (4, 8, 0.6506574749946594), (5, 10, 0.6422803997993469),
-    (6, 12, 0.6323944926261902), (7, 14, 0.6238881945610046),
-    (8, 16, 0.6179242134094238), (9, 20, 0.6117205619812012),
-    (10, 24, 0.6056254506111145), (11, 28, 0.5996546149253845),
-    (12, 32, 0.595111608505249), (13, 36, 0.5898059010505676),
-    (14, 40, 0.5841207504272461), (15, 44, 0.5793169140815735),
-    (16, 48, 0.5756109356880188), (17, 56, 0.5715053081512451),
-    (18, 64, 0.5678795576095581), (19, 72, 0.564716100692749),
-    (20, 80, 0.5618601441383362), (21, 88, 0.558756411075592),
-    (22, 96, 0.5559707283973694), (23, 104, 0.5533583164215088),
-    (24, 112, 0.5510061979293823), (25, 128, 0.5486454963684082),
-    (26, 144, 0.5460535883903503), (27, 160, 0.5438601970672607),
-    (28, 176, 0.541716456413269), (29, 192, 0.5395599603652954),
-    (30, 208, 0.5375436544418335), (31, 224, 0.5357033014297485),
-    (32, 240, 0.53408282995224),
-]
-
-# same revision: stl_sc Non-IID, momentum=0.9, lr_alpha=1e-3, chunk_rounds=4
-# (exercises chunk boundaries, eval_every>1 and the k=√2 growth floor)
-_GOLDEN_STL_SC_MOM = [
-    (0, 0, 0.6931471824645996), (2, 6, 0.6386178731918335),
-    (4, 12, 0.5672575235366821), (6, 20, 0.538230836391449),
-    (8, 28, 0.5201643109321594), (10, 36, 0.509807288646698),
-    (12, 48, 0.5066706538200378), (14, 60, 0.5050743818283081),
-    (16, 72, 0.5042514204978943), (18, 84, 0.5039029717445374),
-]
-
 
 @pytest.fixture(scope="module")
 def golden_problem():
-    x, y = make_binary_classification(n=512, d=16, seed=3)
-    lam = 1e-2
-    data = {k: jnp.asarray(v)
-            for k, v in partition_iid(x, y, 4, seed=0).items()}
-    xj, yj = jnp.asarray(x), jnp.asarray(y)
-    loss_fn = lambda p, b: logreg.loss_fn(p, b, lam)
-    eval_fn = lambda p: logreg.full_objective(p, xj, yj, lam)
-    return loss_fn, eval_fn, logreg.init_params(None, 16), data
+    return G.golden_problem()
 
 
 def test_engine_stl_sc_dense_bit_exact_with_pre_refactor_trace(golden_problem):
     loss_fn, eval_fn, p0, data = golden_problem
-    cfg = TrainConfig(algo="stl_sc", eta1=0.5, T1=16, k1=2.0, n_stages=4,
-                      iid=True, batch_per_client=8, seed=0)
-    hist = simulate.run(loss_fn, p0, data, cfg, eval_fn, eval_every=1)
-    got = [(h.round, h.iteration, float(h.value)) for h in hist]
-    assert got == [(r, i, v) for r, i, v in _GOLDEN_STL_SC]
+    hist = simulate.run(loss_fn, p0, data, TrainConfig(**G.STL_SC_CFG),
+                        eval_fn, **G.STL_SC_RUN)
+    assert G.trace(hist) == G.GOLDEN_STL_SC
 
 
 def test_engine_stl_sc_momentum_chunked_bit_exact(golden_problem):
     loss_fn, eval_fn, p0, data = golden_problem
-    cfg = TrainConfig(algo="stl_sc", eta1=0.3, T1=12, k1=3.0, n_stages=3,
-                      iid=False, batch_per_client=8, momentum=0.9, seed=7)
-    hist = simulate.run(loss_fn, p0, data, cfg, eval_fn, eval_every=2,
-                        lr_alpha=1e-3, chunk_rounds=4)
-    got = [(h.round, h.iteration, float(h.value)) for h in hist]
-    assert got == [(r, i, v) for r, i, v in _GOLDEN_STL_SC_MOM]
+    hist = simulate.run(loss_fn, p0, data, TrainConfig(**G.STL_SC_MOM_CFG),
+                        eval_fn, **G.STL_SC_MOM_RUN)
+    assert G.trace(hist) == G.GOLDEN_STL_SC_MOM
 
 
 # ---------------------------------------------------------------------------

@@ -14,9 +14,9 @@ The decisive invariants (ISSUE 5 acceptance):
     two hops per leaf and reconciles bit-exactly (bytes; modeled seconds
     to float-sum precision) with both the run totals and the tree-level
     ``round_bytes``/``round_time``;
-  * tag discipline — config and sync-step tags must agree: a flat step
-    under a hierarchical config, mismatched n_pods, or streaming+
-    hierarchical are refused with actionable errors;
+  * one round — the config and the topology the sync step carries must
+    agree: a flat step under a hierarchical config, mismatched n_pods or
+    a mismatched inter-pod reducer are refused with actionable errors;
   * mesh structure — on a (pod, data, model) mesh the two-level round's
     collectives split into data-axis-only (intra-pod) and pod-axis-only
     (inter-pod) traffic, where the flat round moves everything across
@@ -37,7 +37,7 @@ from repro.comm import DenseMean, QuantizedMean, get_reducer
 from repro.configs.base import TrainConfig
 from repro.core import local_sgd as LS
 from repro.core.stl_sgd import StagewiseDriver
-from repro.engine import Hierarchical, topology_for
+from repro.engine import Hierarchical, Star, topology_for
 from repro.utils.tree import tree_broadcast_leading, tree_mean_leading
 
 N_CLIENTS, N_PODS = 4, 2  # the 2-pod × 2-client grid
@@ -102,8 +102,8 @@ def test_two_level_single_pod_bit_exact_with_flat_round():
     hier = jax.jit(LS.build_sync_step(None, hierarchical=True, n_pods=1,
                                       inter_reducer="int8"))
     _tree_equal(flat(state), hier(state))
-    assert LS.build_sync_step(None, hierarchical=True, n_pods=1).hierarchical \
-        is False
+    assert type(LS.build_sync_step(None, hierarchical=True,
+                                   n_pods=1).topology) is Star
 
 
 def test_hierarchical_dense_dense_collapses_to_flat_mean():
@@ -111,11 +111,11 @@ def test_hierarchical_dense_dense_collapses_to_flat_mean():
     not merely allclose) — the contract the driver's rail relies on."""
     stacked = _state(n=8)["params"]
     topo = Hierarchical(n_pods=2, intra=DenseMean(), inter=DenseMean())
-    assert topo.all_dense
+    assert not topo.stateful
     mean, _ = topo.reduce(stacked, topo.init_state(stacked),
                           jax.random.key(1))
     _tree_equal(mean, tree_mean_leading(stacked))
-    assert not Hierarchical(n_pods=2, inter=QuantizedMean()).all_dense
+    assert Hierarchical(n_pods=2, inter=QuantizedMean()).stateful
 
 
 def test_two_level_rejects_indivisible_clients():
@@ -139,7 +139,8 @@ def test_driver_trace_bit_exact_with_hierarchical_replay(inter):
     sync_step = LS.build_sync_step(None, hierarchical=True, n_pods=N_PODS,
                                    inter_reducer=inter)
     drv = StagewiseDriver(tcfg, _toy_train_step, sync_step)
-    assert drv.hierarchical and drv.n_pods == N_PODS
+    assert isinstance(drv.topology, Hierarchical)
+    assert drv.topology.n_pods == N_PODS
     ds = drv.run(_state(), iter([None] * 256))
 
     # replay: same stage stream, same drift, sync via the topology the
@@ -155,7 +156,7 @@ def test_driver_trace_bit_exact_with_hierarchical_replay(inter):
                 state = _drift(state, stage.eta)
                 done += 1
             rng = jax.random.fold_in(jax.random.key(0), state["step"])
-            if topo.all_dense:
+            if not topo.stateful:
                 consensus, _ = topo.reduce(state["params"], None, rng)
             else:
                 if comm is None:
@@ -206,7 +207,7 @@ def test_driver_hierarchical_leaf_ledger_reconciles():
 
 
 # ---------------------------------------------------------------------------
-# Tag discipline: config and sync step must describe the same round
+# One round: the config and the sync step's topology must agree
 # ---------------------------------------------------------------------------
 
 def test_driver_refuses_flat_step_under_hierarchical_config():
@@ -235,14 +236,15 @@ def test_driver_refuses_inter_reducer_mismatch():
 
 
 def test_hier_tagged_step_implies_hierarchical_under_star_config():
-    """Mirror of the streaming-tag rule: the executed round wins, and the
-    ledger follows it (jit-wrapped tags included)."""
+    """Under a star config the executed round wins, and the ledger
+    follows it (read through jit's wrapper)."""
     sync = jax.jit(LS.build_sync_step(None, hierarchical=True,
                                       n_pods=N_PODS, inter_reducer="int8"))
     drv = StagewiseDriver(TrainConfig(algo="local", T1=4, k1=2.0,
                                       n_stages=1), _toy_train_step, sync)
-    assert drv.hierarchical and drv.n_pods == N_PODS
-    assert drv.inter_reducer.name == "int8"
+    assert isinstance(drv.topology, Hierarchical)
+    assert drv.topology.n_pods == N_PODS
+    assert drv.topology.inter.name == "int8"
     ds = drv.run(_state(), iter([None] * 32))
     assert {l["hop"] for l in ds.leaf_ledger} == {"intra_pod", "inter_pod"}
 
@@ -254,7 +256,7 @@ def test_single_pod_config_runs_flat():
                        topology="hier", n_pods=1)
     sync = LS.build_sync_step(None, hierarchical=True, n_pods=1)
     drv = StagewiseDriver(tcfg, _toy_train_step, sync)
-    assert not drv.hierarchical
+    assert type(drv.topology) is Star
     ds = drv.run(_state(), iter([None] * 32))
     assert {l["hop"] for l in ds.leaf_ledger} == {"uplink"}
 
@@ -268,7 +270,8 @@ def test_streaming_hierarchical_driver_composes():
     sync_s = LS.build_sync_step("int8", streaming=True,
                                 hierarchical=True, n_pods=N_PODS,
                                 inter_reducer="int8")
-    assert sync_s.streaming and sync_s.hierarchical
+    assert sync_s.topology.streaming
+    assert isinstance(sync_s.topology, Hierarchical)
     cfg = dict(algo="local", T1=4, k1=2.0, n_stages=1, reducer="int8")
     drv_b = StagewiseDriver(TrainConfig(**cfg, topology="hier",
                                         n_pods=N_PODS), _toy_train_step,
@@ -276,8 +279,7 @@ def test_streaming_hierarchical_driver_composes():
     drv_s = StagewiseDriver(TrainConfig(**cfg, topology="streaming-hier",
                                         n_pods=N_PODS), _toy_train_step,
                             sync_s)
-    assert drv_s.streaming and drv_s.hierarchical
-    assert drv_s.build_topology().name == "streaming-hier"
+    assert drv_s.topology.name == "streaming-hier"
     ds_b = drv_b.run(_state(), iter([None] * 32))
     ds_s = drv_s.run(_state(), iter([None] * 32))
     assert jax.tree.all(jax.tree.map(
@@ -290,6 +292,19 @@ def test_streaming_hierarchical_driver_composes():
     assert ds_s.comm_bytes_total == ds_b.comm_bytes_total
     assert {l["hop"] for l in ds_s.leaf_ledger} == {"intra_pod", "inter_pod"}
     assert sum(l["bytes"] for l in ds_s.leaf_ledger) == ds_s.comm_bytes_total
+
+
+def test_sync_step_runs_a_given_topology():
+    """A Topology handed to build_sync_step (as the launcher hands the
+    config's) is the round it runs and carries: its pods need no pod mesh
+    axis, and the round is the keyword-built one bit for bit."""
+    topo = topology_for(TrainConfig(topology="hier", n_pods=N_PODS,
+                                    inter_reducer="int8"))
+    step = LS.build_sync_step(topo)
+    assert step.topology is topo
+    ref = LS.build_sync_step(None, hierarchical=True, n_pods=N_PODS,
+                             inter_reducer="int8")
+    _tree_equal(jax.jit(step)(_state()), jax.jit(ref)(_state()))
 
 
 def test_build_train_steps_two_level_needs_pod_axis():
@@ -363,8 +378,9 @@ assert tuple(ca) == ("pod", "data"), ca
 with jax.sharding.set_mesh(mesh):
     local_step, sync_step, _ = LS.build_train_steps(
         cfg, mesh, client_axis=ca, microbatch=1, inter_reducer="int8")
-    assert sync_step.hierarchical and sync_step.n_pods == 2
-    assert sync_step.inter_reducer.name == "int8"
+    assert sync_step.topology.name == "hierarchical"
+    assert sync_step.topology.n_pods == 2
+    assert sync_step.topology.inter.name == "int8"
     cl = jax.jit(local_step, in_shardings=(st_sh, b_sh, None),
                  out_shardings=(st_sh, None)).lower(state, batch,
                                                     0.1).compile()

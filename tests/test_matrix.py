@@ -280,7 +280,8 @@ def _driver_cell(topology, reducer):
                        topology=topology, n_pods=N_PODS,
                        count_downlink=True)
     drv = StagewiseDriver(tcfg, _toy_train_step, sync)
-    assert drv.streaming == streaming and drv.hierarchical == hier
+    assert drv.topology.streaming == streaming
+    assert isinstance(drv.topology, Hierarchical) == hier
     return drv.run(_driver_state(), iter([None] * 64))
 
 

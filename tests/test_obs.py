@@ -35,7 +35,8 @@ import pytest
 from repro import runtime
 from repro.configs.base import TrainConfig
 from repro.core import simulate
-from repro.core.local_sgd import build_sync_step, sync_step_tags
+from repro.core.local_sgd import build_sync_step, sync_step_topology
+from repro.engine import Hierarchical
 from repro.data import make_binary_classification, partition_iid
 from repro.models import logreg
 from repro.obs import (
@@ -473,7 +474,7 @@ def test_bench_diff_cli_exit_codes(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Structured logging and sync-step tags
+# Structured logging and the sync step's topology
 # ---------------------------------------------------------------------------
 
 def test_structured_logger_jsonl_and_levels():
@@ -505,13 +506,14 @@ def test_structured_logger_printf_compat_and_clock():
 
 def test_sync_step_tags_survive_jit():
     step = build_sync_step("int8", streaming=True)
-    tags = sync_step_tags(jax.jit(step))
-    # tags carry the built Reducer objects (the driver re-prices with the
-    # exact instance the round transmits), not just their names
-    assert tags["reducer"].name == "int8" and tags["streaming"]
-    assert not tags["hierarchical"]
+    topo = sync_step_topology(jax.jit(step))
+    # the step carries the Topology it runs, built Reducer objects and all
+    # (the driver prices the exact instance the round transmits)
+    assert topo is step.topology
+    assert topo.reducer.name == "int8" and topo.streaming
+    assert not isinstance(topo, Hierarchical)
     hier = build_sync_step("dense", hierarchical=True, n_pods=2,
                            inter_reducer="int8")
-    tags = sync_step_tags(hier)
-    assert tags["hierarchical"] and tags["n_pods"] == 2
-    assert tags["inter_reducer"].name == "int8"
+    topo = sync_step_topology(hier)
+    assert isinstance(topo, Hierarchical) and topo.n_pods == 2
+    assert topo.inter.name == "int8"

@@ -2,9 +2,9 @@
 
 The decisive invariants:
   * with heterogeneity disabled, EventBackend's synchronous path is
-    bit-exact with the vmapped simulator — pinned against the same PR 2
-    golden stl_sc trace as tests/test_engine.py, and bitwise-equal to
-    ``simulate.run`` for EveryStep/FixedPeriod;
+    bit-exact with the vmapped simulator — pinned against the same
+    golden stl_sc trace as tests/test_engine.py (tests/goldens.py), and
+    bitwise-equal to ``simulate.run`` for EveryStep/FixedPeriod;
   * the clock is pure accounting: stragglers stretch modeled wall-clock
     without touching the trajectory; barrier rounds are priced at the
     slowest active client;
@@ -21,11 +21,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import goldens as G
 from repro.comm import NetworkModel, StalenessWeightedMean, get_reducer
 from repro.configs.base import TrainConfig
 from repro.core import simulate
 from repro import runtime
-from repro.data import make_binary_classification, partition_iid
 from repro.engine import (
     AdaptivePeriod,
     Algorithm,
@@ -36,7 +36,6 @@ from repro.engine import (
     get_algorithm,
     make_async,
 )
-from repro.models import logreg
 from repro.runtime import (
     Clock,
     EventBackend,
@@ -45,48 +44,13 @@ from repro.runtime import (
     sample_clients,
 )
 
-# (round, iteration, objective) trace of the pre-engine core/simulate.py
-# (commit f5d4d18) — stl_sc + DenseMean, seed 0, same problem as
-# tests/test_engine.py::_GOLDEN_STL_SC. The event runtime must land on it
-# bit-for-bit when heterogeneity is disabled.
-_GOLDEN_STL_SC = [
-    (0, 0, 0.6931471824645996), (1, 2, 0.6789301633834839),
-    (2, 4, 0.6675747632980347), (3, 6, 0.6584702134132385),
-    (4, 8, 0.6506574749946594), (5, 10, 0.6422803997993469),
-    (6, 12, 0.6323944926261902), (7, 14, 0.6238881945610046),
-    (8, 16, 0.6179242134094238), (9, 20, 0.6117205619812012),
-    (10, 24, 0.6056254506111145), (11, 28, 0.5996546149253845),
-    (12, 32, 0.595111608505249), (13, 36, 0.5898059010505676),
-    (14, 40, 0.5841207504272461), (15, 44, 0.5793169140815735),
-    (16, 48, 0.5756109356880188), (17, 56, 0.5715053081512451),
-    (18, 64, 0.5678795576095581), (19, 72, 0.564716100692749),
-    (20, 80, 0.5618601441383362), (21, 88, 0.558756411075592),
-    (22, 96, 0.5559707283973694), (23, 104, 0.5533583164215088),
-    (24, 112, 0.5510061979293823), (25, 128, 0.5486454963684082),
-    (26, 144, 0.5460535883903503), (27, 160, 0.5438601970672607),
-    (28, 176, 0.541716456413269), (29, 192, 0.5395599603652954),
-    (30, 208, 0.5375436544418335), (31, 224, 0.5357033014297485),
-    (32, 240, 0.53408282995224),
-]
-
-
 @pytest.fixture(scope="module")
 def golden_problem():
-    x, y = make_binary_classification(n=512, d=16, seed=3)
-    lam = 1e-2
-    data = {k: jnp.asarray(v)
-            for k, v in partition_iid(x, y, 4, seed=0).items()}
-    xj, yj = jnp.asarray(x), jnp.asarray(y)
-    loss_fn = lambda p, b: logreg.loss_fn(p, b, lam)
-    eval_fn = lambda p: logreg.full_objective(p, xj, yj, lam)
-    return loss_fn, eval_fn, logreg.init_params(None, 16), data
+    return G.golden_problem()
 
 
 def _golden_cfg(**kw):
-    base = dict(algo="stl_sc", eta1=0.5, T1=16, k1=2.0, n_stages=4,
-                iid=True, batch_per_client=8, seed=0)
-    base.update(kw)
-    return TrainConfig(**base)
+    return TrainConfig(**{**G.STL_SC_CFG, **kw})
 
 
 # ---------------------------------------------------------------------------
@@ -132,8 +96,7 @@ def test_event_backend_stl_sc_bit_exact_with_golden_trace(golden_problem):
     loss_fn, eval_fn, p0, data = golden_problem
     res = runtime.run(loss_fn, p0, data, _golden_cfg(), eval_fn,
                       eval_every=1)
-    got = [(h.round, h.iteration, float(h.value)) for h in res.history]
-    assert got == [(r, i, v) for r, i, v in _GOLDEN_STL_SC]
+    assert G.trace(res.history) == G.GOLDEN_STL_SC
     # and the clock priced 32 homogeneous barrier rounds
     assert res.rounds == 32
     assert res.wall_clock_s > 0.0

@@ -394,8 +394,8 @@ def test_driver_accepts_streaming_topology_and_carries_leaf_ledger():
     tcfg = TrainConfig(algo="local", T1=8, k1=2.0, n_stages=1,
                        topology="streaming")
     drv = StagewiseDriver(tcfg, train_step, sync_step)
-    assert drv.streaming
-    assert drv.reducer.name == "int8"
+    assert drv.topology.streaming
+    assert drv.topology.reducer.name == "int8"
     batches = iter([{"x": None}] * 64)
     ds = drv.run(_perturb(_driver_state(d=d)), batches)
     assert ds.rounds_total == 4
@@ -403,11 +403,11 @@ def test_driver_accepts_streaming_topology_and_carries_leaf_ledger():
     assert sum(l["bytes"] for l in ds.leaf_ledger) == ds.comm_bytes_total
     assert math.fsum(l["time_s"] for l in ds.leaf_ledger) \
         == pytest.approx(ds.comm_time_s, rel=1e-12)
-    # a streaming-tagged sync_step implies the per-leaf round even under
-    # a plain "star" config
+    # a streaming sync_step runs (and is priced as) the per-leaf round
+    # even under a plain "star" config
     drv2 = StagewiseDriver(TrainConfig(algo="local", T1=4, k1=2.0,
                                        n_stages=1), train_step, sync_step)
-    assert drv2.streaming
+    assert drv2.topology.streaming
     # a hierarchical config still refuses a *flat* sync step (streaming or
     # not): the ledger would price an inter-pod hop the round never crosses
     with pytest.raises(ValueError, match="build_sync_step"):

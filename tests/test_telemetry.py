@@ -16,7 +16,8 @@ The decisive invariants of the trajectory half of ``repro.obs``:
     ``slo_breach`` spans on the virtual clock;
   * ``ProfileSession`` reconciles: every profiled span carries both
     modeled and measured seconds, span durations equal the recorded
-    measured times, and wrapping never hides ``build_sync_step`` tags;
+    measured times, and wrapping never hides the topology a
+    ``build_sync_step`` round carries;
   * histogram percentiles are numpy-exact below ``cap`` and degrade to a
     flagged, deterministic reservoir above it;
   * ``read_jsonl`` round-trips ``write_jsonl`` span logs;
@@ -31,7 +32,7 @@ import numpy as np
 import pytest
 
 from repro import runtime
-from repro.core.local_sgd import build_sync_step, sync_step_tags
+from repro.core.local_sgd import build_sync_step, sync_step_topology
 from repro.core.stl_sgd import StagewiseDriver, driver_state
 from repro.obs import (
     MODELED,
@@ -364,8 +365,8 @@ def test_profile_wrap_preserves_sync_step_tags():
     raw = build_sync_step("int8")
     prof = ProfileSession()
     wrapped = prof.wrap(jax.jit(raw), "sync_step", 1e-3)
-    assert sync_step_tags(wrapped) == sync_step_tags(raw)
-    assert sync_step_tags(wrapped)["reducer"] is not None
+    assert sync_step_topology(wrapped) is sync_step_topology(raw)
+    assert sync_step_topology(wrapped).reducer.name == "int8"
 
 
 def test_profile_session_raises_when_profiler_cannot_start(tmp_path):
