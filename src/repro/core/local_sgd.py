@@ -41,6 +41,7 @@ from repro.configs.base import ArchConfig
 from repro.core.round import end_round
 from repro.engine.topology import Star, Topology, get_topology
 from repro.kernels.flash_attention.causal import kernel_mesh
+from repro.kernels.replica_mean import kernel_view, replica_mean
 from repro.models import transformer as TF
 from repro.obs.lowered import lowering_counter
 from repro.optim import make_optimizer
@@ -167,6 +168,28 @@ def _gather_narrow(replicas, consensus, mesh, client_axis):
     return jax.tree.map(leaf, replicas, consensus)
 
 
+def _colocated(mesh) -> bool:
+    """Whether every replica of a leaf sits on one device: no mesh, or a
+    mesh of one device."""
+    return mesh is None or mesh.size == 1
+
+
+def _average_in_place(replicas, consensus):
+    """``consensus`` with each leaf that has a ``kernel_view`` replaced, on
+    TPU, by ``replica_mean`` of its replicas: one in-place pass for the
+    four of ``jnp.mean`` + ``broadcast_to``, the same float32 sum divided
+    by n and rounded once. Every other platform lowers ``consensus`` as it
+    is, and XLA drops whichever of the two a program does not use."""
+    def leaf(x, mean):
+        if kernel_view(x.shape, x.dtype) is None:
+            return mean
+        return jax.lax.platform_dependent(
+            x, mean, tpu=lambda x, _: replica_mean(x),
+            default=lambda _, mean: mean)
+
+    return jax.tree.map(leaf, replicas, consensus)
+
+
 def build_sync_step(reducer=None, *, base_seed: int = 0,
                     streaming: bool = False, hierarchical: bool = False,
                     n_pods: int = 2, inter_reducer="int8", mesh=None,
@@ -192,8 +215,11 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
     keeps the round's output params and moments split over the client
     axis, as its input was. Where a dense blocking star runs with one
     replica per device and no other axis splits a leaf, its narrow
-    leaves are averaged through ``_gathered_mean``; each round lowered
-    counts once under ``sync.lowered{path=scatter_gather|all_reduce}``.
+    leaves are averaged through ``_gathered_mean``; where it runs with all
+    replicas on one device (``mesh`` None or of one device), its leaves
+    are averaged in place through ``_average_in_place``. Each round
+    lowered counts once under
+    ``sync.lowered{path=scatter_gather|colocated|all_reduce}``.
     """
     topology = get_topology(
         reducer if isinstance(reducer, Topology)
@@ -207,6 +233,7 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
         # in a profile's op metadata
         n = jax.tree.leaves(state["params"])[0].shape[0]
         gather = dense_star and _one_replica_per_device(mesh, client_axis, n)
+        colocated = dense_star and _colocated(mesh)
         rng = jax.random.fold_in(jax.random.key(base_seed), state["step"])
         out = dict(state)
         with jax.named_scope("reduce"):
@@ -224,8 +251,12 @@ def build_sync_step(reducer=None, *, base_seed: int = 0,
         if gather:
             out.update({k: _gather_narrow(state[k], out[k], mesh, client_axis)
                         for k in ("params", "opt")})
+        if colocated:
+            out.update({k: _average_in_place(state[k], out[k])
+                        for k in ("params", "opt")})
         out["step"] = _sync_lowered(
-            state["step"], path="scatter_gather" if gather else "all_reduce")
+            state["step"], path="scatter_gather" if gather
+            else "colocated" if colocated else "all_reduce")
         return out
 
     # the driver prices the topology the round runs

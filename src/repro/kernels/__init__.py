@@ -8,5 +8,7 @@
 #   quantize/         fused stochastic-round quantize + dequant-accumulate
 #                     (the compressed communication round, repro.comm)
 #   ssd/              Mamba2 SSD chunked scan in matmul-dual (MXU) form
+#   replica_mean.py   the one-chip round: co-located client replicas averaged
+#                     in one in-place pass (core.local_sgd on TPU)
 # Each package: kernel.py (pl.pallas_call + BlockSpec), ops.py (public
 # jit-able wrapper), ref.py (pure-jnp oracle used by the allclose tests).

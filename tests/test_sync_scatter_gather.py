@@ -2,8 +2,9 @@
 and a bfloat16 all-gather (``local_sgd._gathered_mean``), on fake CPU
 devices: bit for bit the all-reduce's ``tree_mean_leading`` +
 ``tree_broadcast_leading`` where each device holds one client replica,
-and today's all-reduce wherever it does not engage. The round runs in a
-subprocess so that XLA's device-count flag does not leak into this one.
+and today's all-reduce wherever it does not engage; two clients on one
+device count ``colocated`` and lower, on the CPU, to the same mean. The
+round runs in a subprocess so that XLA's device-count flag does not leak into this one.
 """
 import json
 import os
@@ -35,13 +36,14 @@ LEAVES = {"stack": (4, 16, 256), "embed": (40, 1024), "norm": (4, 256),
           "odd": (9, 10), "vec": (64,)}
 
 
-def case(data, model):
+def case(data, model, clients=None):
+    clients = clients or data
     mesh = jax.make_mesh((data, model), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2,
                          devices=jax.devices()[:data * model])
     key = jax.random.key(7)
     leaf = lambda i, s, dt: (3 * jax.random.normal(
-        jax.random.fold_in(key, i), (data,) + s)).astype(dt)
+        jax.random.fold_in(key, i), (clients,) + s)).astype(dt)
     state = {"params": {k: leaf(i, s, jnp.bfloat16)
                         for i, (k, s) in enumerate(LEAVES.items())},
              "opt": {"mu": {k: leaf(10 + i, s, jnp.float32)
@@ -52,12 +54,13 @@ def case(data, model):
     reg = obs_metrics.registry()
     count = lambda path: (reg["sync.lowered"].value(path=path)
                           if "sync.lowered" in reg else 0.0)
-    before = {p: count(p) for p in ("scatter_gather", "all_reduce")}
+    before = {p: count(p) for p in ("scatter_gather", "colocated",
+                                    "all_reduce")}
     sync = jax.jit(LS.build_sync_step(mesh=mesh, client_axis="data"))
     out = sync(state)
     text = sync.lower(state).compile().as_text()
     ref = jax.jit(lambda s: dict(s, **{
-        k: tree_broadcast_leading(tree_mean_leading(s[k]), data)
+        k: tree_broadcast_leading(tree_mean_leading(s[k]), clients)
         for k in ("params", "opt")}))(state)
     same = {}
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(out),
@@ -75,7 +78,8 @@ def case(data, model):
                                {k: out[k] for k in ("params", "opt")}))}
 
 
-print(json.dumps({"4x1": case(4, 1), "2x4": case(2, 4)}))
+print(json.dumps({"4x1": case(4, 1), "2x4": case(2, 4),
+                  "1x1": case(1, 1, clients=2)}))
 """
 
 
@@ -93,7 +97,7 @@ LEAF_PATHS = [f"['{part}']{mu}['{name}']"
               for name in ("stack", "embed", "norm", "odd", "vec")]
 
 
-@pytest.mark.parametrize("mesh", ["4x1", "2x4"])
+@pytest.mark.parametrize("mesh", ["4x1", "2x4", "1x1"])
 @pytest.mark.parametrize("leaf", LEAF_PATHS)
 def test_round_equals_all_reduce_mean_bit_for_bit(rounds, mesh, leaf):
     """Every leaf of the round, bfloat16 params and float32 momentum,
@@ -111,7 +115,8 @@ def test_one_replica_per_device_scatters_and_gathers(rounds):
     scatter (the float32 momentum keeps the all-reduce); the consensus
     stays split over ``data``."""
     r = rounds["4x1"]
-    assert r["lowered"] == {"scatter_gather": 1.0, "all_reduce": 0.0}
+    assert r["lowered"] == {"scatter_gather": 1.0, "colocated": 0.0,
+                            "all_reduce": 0.0}
     assert r["reduce_scatters"] == 2
     assert r["sharded"]
 
@@ -121,9 +126,19 @@ def test_model_axis_keeps_the_all_reduce(rounds):
     round lowers as before, counts ``all_reduce`` and has no
     reduce-scatter."""
     r = rounds["2x4"]
-    assert r["lowered"] == {"scatter_gather": 0.0, "all_reduce": 1.0}
+    assert r["lowered"] == {"scatter_gather": 0.0, "colocated": 0.0,
+                            "all_reduce": 1.0}
     assert r["reduce_scatters"] == 0
     assert r["sharded"]
+
+
+def test_two_clients_on_one_device_count_colocated(rounds):
+    """Two clients on a one-device mesh: the round counts ``colocated``,
+    has no collective, and on the CPU is the mean above bit for bit."""
+    r = rounds["1x1"]
+    assert r["lowered"] == {"scatter_gather": 0.0, "colocated": 1.0,
+                            "all_reduce": 0.0}
+    assert r["reduce_scatters"] == 0
 
 
 @pytest.mark.parametrize("shape,dim", [
@@ -147,7 +162,8 @@ def test_scatter_dim_by_shape(shape, dim):
 
 def test_no_mesh_or_one_device_keeps_the_all_reduce():
     """Without a mesh, with one client per device on one device, or with
-    two clients sharing a device, the round is today's all-reduce."""
+    two clients sharing a device, the round neither scatters nor gathers:
+    its replicas share a device, so it averages them in place."""
     import jax
 
     one = jax.make_mesh((1, 1), ("data", "model"),
@@ -155,3 +171,4 @@ def test_no_mesh_or_one_device_keeps_the_all_reduce():
     assert not LS._one_replica_per_device(None, "data", 4)
     assert not LS._one_replica_per_device(one, "data", 1)
     assert not LS._one_replica_per_device(one, "data", 2)
+    assert LS._colocated(None) and LS._colocated(one)

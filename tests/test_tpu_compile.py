@@ -10,6 +10,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+import math
 import os
 import re
 
@@ -250,37 +251,68 @@ def test_minicpm3_four_chip_round_gathers_in_leaf_dtype(topo):
     for shape in scattered:
         assert f"bf16[{','.join(map(str, shape))}]" in gathered, shape
     assert not _typed(text, "copy")
+    assert "tpu_custom_call" not in text   # the replicas share no device
     link = H.collective_summary(H.parse_collectives_nested(
         text, {"data": 4, "model": 1}))["by_axes"]["data"]
     assert link <= 0.9 * ALL_REDUCE_ROUND_LINK_BYTES, link
 
 
-def _ops(text):
-    """Each instruction of an HLO text as its result type and opcode."""
-    return sorted(re.findall(r"= (\S+) ([a-z][\w\-]*)\(", text))
+# the one-chip round's temp bytes as jnp.mean + broadcast_to, before the
+# replicas were averaged in place, compiled with jax 0.9.0 and its libtpu
+MEAN_ROUND_TEMP = {"minicpm3-4b": 1_129_433_088, "mamba2-2.7b": 1_299_578_880}
+IN_PLACE_TEMP = 64e6         # the in-place round's temp bytes at most
+RELAYOUT_BYTES = 100e6       # no copy, transpose or fusion this large
+DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+               "u32": 4, "f32": 4}
+
+
+def _entry_outputs(text, ops):
+    """Bytes of the result of each ``ops`` instruction of an HLO text's
+    entry computation."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    return [sum(DTYPE_BYTES[t] * math.prod(map(int, filter(None, d.split(","))))
+                for t, d in re.findall(r"(\w+)\[([\d,]*)\]", result))
+            for result, op in re.findall(r"= (.+?) ([a-z][\w\-]*)\(", entry)
+            if op in ops]
+
+
+def _one_chip_round_averages_in_place(topo, name, layers):
+    """The benchmark's one-chip round of ``name`` (two clients sharing the
+    chip, the state donated): it counts ``colocated``, averages its leaves
+    through the kernel (``tpu_custom_call``) with no collective, keeps at
+    most ``IN_PLACE_TEMP`` of temp bytes against ``MEAN_ROUND_TEMP``, and
+    its entry computation relays out no leaf: no copy, transpose or fusion
+    of ``RELAYOUT_BYTES`` or more."""
+    cfg = get_arch(name, layers=layers)
+    before = _lowered("colocated")
+    _, _, sync, state = _bench_steps(topo, cfg, 1, CLIENTS)
+    compiled = jax.jit(sync, donate_argnums=(0,)).lower(state).compile()
+    assert _lowered("colocated") > before
+    _fits(compiled)
+
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert not re.search(
+        r" (all-reduce|all-gather|reduce-scatter)(-start)?\(", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= IN_PLACE_TEMP < MEAN_ROUND_TEMP[name], temp
+    big = [b for b in _entry_outputs(text, ("copy", "transpose", "fusion"))
+           if b >= RELAYOUT_BYTES]
+    assert not big, big
 
 
 def test_minicpm3_one_chip_round_keeps_the_all_reduce_mean(topo):
-    """The benchmark's one-chip round (two clients sharing the chip) is
-    not touched: it counts ``all_reduce``, has no collective, and
-    compiles to the ops of ``tree_mean_leading`` + ``tree_broadcast_leading``
-    laid out over the client axis, the round it was before."""
-    from repro.utils.tree import tree_broadcast_leading, tree_mean_leading
+    """MiniCPM3-l4's one-chip round keeps the all-reduce round's mean (the
+    float32 sum of the two replicas over two, rounded once; bit for bit
+    on the CPU in tests/test_replica_mean.py) but computes it in place:
+    see ``_one_chip_round_averages_in_place``. Its stacked w_dkv
+    (2560 x 288) is laid out with 2560 minor and taken in that view."""
+    _one_chip_round_averages_in_place(topo, "minicpm3-4b", 4)
 
-    cfg = get_arch("minicpm3-4b", layers=4)
-    before = _lowered("all_reduce")
-    mesh, _, sync, state = _bench_steps(topo, cfg, 1, CLIENTS)
-    compiled = jax.jit(sync, donate_argnums=(0,)).lower(state).compile()
-    assert _lowered("all_reduce") > before
-    _fits(compiled)
 
-    def mean_round(s):
-        out = {k: tree_broadcast_leading(tree_mean_leading(s[k]), CLIENTS)
-               for k in ("params", "opt")}
-        return dict(s, **LS._client_sharded(out, mesh, "data"))
-
-    ref = jax.jit(mean_round, donate_argnums=(0,)).lower(state).compile()
-    text = compiled.as_text()
-    assert not re.search(
-        r" (all-reduce|all-gather|reduce-scatter)(-start)?\(", text)
-    assert _ops(text) == _ops(ref.as_text())
+def test_mamba2_one_chip_round_averages_in_place(topo):
+    """Mamba2-l8's one-chip round, whose stacked w_in (2560 x 10576, half
+    the state's bytes with its momentum) is laid out with 2560 minor:
+    see ``_one_chip_round_averages_in_place``."""
+    _one_chip_round_averages_in_place(topo, "mamba2-2.7b", 8)
